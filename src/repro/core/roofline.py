@@ -182,17 +182,8 @@ class RooflineTerms:
         return self
 
 
-def cost_analysis_dict(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: older releases
-    return a one-element list of dicts, newer ones a plain dict."""
-    ca = compiled.cost_analysis() or {}
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def terms_from_compiled(compiled) -> RooflineTerms:
-    ca = cost_analysis_dict(compiled)
+    ca = compiled.cost_analysis() or {}
     coll = parse_collectives(compiled.as_text())
     return RooflineTerms(
         flops=float(ca.get("flops", 0.0)),

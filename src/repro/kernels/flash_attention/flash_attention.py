@@ -40,15 +40,18 @@ def _fa_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale  # (bqG, bk)
 
-    q_i = pl.program_id(1)
-    q_pos = (q_i * bq + jax.lax.broadcasted_iota(jnp.int32, (bq, G), 0)
-             ).reshape(bq * G)
-    k_pos = kv_i * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)[0]
+    # row r of the block is query position q_i*bq + r//G.  The masks compare
+    # r against G*(distance) instead of dividing r, since Mosaic cannot
+    # reshape a (bq, G) iota into rows when G is not tile-aligned:
+    # r//G >= n  <=>  r >= G*n,  and  r//G < n  <=>  r < G*n.
+    row = jax.lax.broadcasted_iota(jnp.int32, (bq * G, bk), 0)
+    k_off = (kv_i * bk - pl.program_id(1) * bq
+             + jax.lax.broadcasted_iota(jnp.int32, (bq * G, bk), 1))
     ok = jnp.ones((bq * G, bk), bool)
     if causal:
-        ok &= q_pos[:, None] >= k_pos[None, :]
+        ok &= row >= G * k_off
     if window > 0:
-        ok &= (q_pos[:, None] - k_pos[None, :]) < window
+        ok &= row < G * (k_off + window)
     s = jnp.where(ok, s, _NEG)
 
     m_prev = m_ref[...]
@@ -72,11 +75,14 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True, window: int = 0,
     """q: (B, Sq, Hq, D); k, v: (B, Skv, Hkv, D). Returns (B, Sq, Hq, D)."""
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
-    assert Hq % Hkv == 0
+    if Hq % Hkv:
+        raise ValueError(f"{Hq} query heads do not group over {Hkv} kv heads")
     G = Hq // Hkv
     bq = min(bq, Sq)
     bk = min(bk, Skv)
-    assert Sq % bq == 0 and Skv % bk == 0, (Sq, Skv, bq, bk)
+    if Sq % bq or Skv % bk:
+        raise ValueError(f"blocks ({bq}, {bk}) do not tile the sequence "
+                         f"lengths ({Sq}, {Skv})")
     scale = 1.0 / math.sqrt(D)
 
     # layout: (B*Hkv, Sq*G, D) with q rows grouped [q_pos-major, G-minor]

@@ -1,4 +1,6 @@
-"""Jit'd public wrapper for the flash-attention kernel."""
+"""Jit'd public wrapper for the flash-attention kernel.  ``interpret`` is
+the caller's choice: True runs the Pallas interpreter on any backend, False
+compiles for the TPU."""
 from __future__ import annotations
 
 from functools import partial
@@ -8,15 +10,9 @@ import jax
 from .flash_attention import flash_attention_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                    "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
-                    bq: int = 128, bk: int = 512,
-                    interpret: bool | None = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+                    bq: int = 128, bk: int = 512, interpret: bool):
     return flash_attention_pallas(q, k, v, causal=causal, window=window,
-                                  bq=bq, bk=bk, interpret=interp)
+                                  bq=bq, bk=bk, interpret=interpret)

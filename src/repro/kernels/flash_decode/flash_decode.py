@@ -67,10 +67,12 @@ def flash_decode_pallas(q, k_cache, v_cache, cur_pos, *, window: int = 0,
     Returns (B, Hq, D).  The scale 1/sqrt(D) is folded into q."""
     B, Hq, D = q.shape
     _, S, Hkv, _ = k_cache.shape
-    assert Hq % Hkv == 0
+    if Hq % Hkv:
+        raise ValueError(f"{Hq} query heads do not group over {Hkv} kv heads")
     G = Hq // Hkv
     bk = min(bk, S)
-    assert S % bk == 0, (S, bk)
+    if S % bk:
+        raise ValueError(f"block {bk} does not tile the cache length {S}")
 
     qg = (q.reshape(B, Hkv, G, D) / math.sqrt(D)).astype(q.dtype)
     qg = qg.reshape(B * Hkv, G, D)

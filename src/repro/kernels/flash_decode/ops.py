@@ -1,4 +1,6 @@
-"""Jit'd public wrapper for the flash-decode kernel."""
+"""Jit'd public wrapper for the flash-decode kernel.  ``interpret`` is the
+caller's choice: True runs the Pallas interpreter on any backend, False
+compiles for the TPU."""
 from __future__ import annotations
 
 from functools import partial
@@ -8,13 +10,8 @@ import jax
 from .flash_decode import flash_decode_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("window", "bk", "interpret"))
 def flash_decode(q, k_cache, v_cache, cur_pos, *, window: int = 0,
-                 bk: int = 512, interpret: bool | None = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+                 bk: int = 512, interpret: bool):
     return flash_decode_pallas(q, k_cache, v_cache, cur_pos, window=window,
-                               bk=bk, interpret=interp)
+                               bk=bk, interpret=interpret)

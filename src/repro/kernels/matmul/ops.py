@@ -8,13 +8,9 @@ import jax
 from .matmul import matmul_pallas
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("bm", "bk", "bn", "interpret"))
 def matmul(a, b, *, bm: int = 256, bk: int = 512, bn: int = 256,
-           interpret: bool | None = None):
-    """Blocked matmul; interpret-mode automatically off-TPU."""
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return matmul_pallas(a, b, bm=bm, bk=bk, bn=bn, interpret=interp)
+           interpret: bool):
+    """Blocked matmul.  ``interpret`` is the caller's choice: True runs the
+    Pallas interpreter on any backend, False compiles for the TPU."""
+    return matmul_pallas(a, b, bm=bm, bk=bk, bn=bn, interpret=interpret)

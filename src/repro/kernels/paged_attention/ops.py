@@ -5,7 +5,8 @@ Three variants, one convention: dense (``paged_decode``), quantized-layout
 scales), and blockwise-sparse (``paged_decode_sparse``: whole blocks below
 an estimated-attention-mass threshold are skipped; the keep mask comes
 from ``ref.block_keep_mask`` so the kernel and the oracle always agree on
-selection).
+selection).  ``interpret`` is the caller's choice: True runs the Pallas
+interpreter on any backend, False compiles for the TPU.
 """
 from __future__ import annotations
 
@@ -18,34 +19,26 @@ from .paged_attention import (paged_decode_pallas, paged_decode_quant_pallas,
 from .ref import block_keep_mask
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
-
-
 @partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode(q, k_pages, v_pages, tables, cur_pos, *, window: int = 0,
-                 interpret: bool | None = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+                 interpret: bool):
     return paged_decode_pallas(q, k_pages, v_pages, tables, cur_pos,
-                               window=window, interpret=interp)
+                               window=window, interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("window", "interpret"))
 def paged_decode_quant(q, k_pages, v_pages, k_scales, v_scales, tables,
-                       cur_pos, *, window: int = 0,
-                       interpret: bool | None = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+                       cur_pos, *, window: int = 0, interpret: bool):
     return paged_decode_quant_pallas(q, k_pages, v_pages, k_scales, v_scales,
                                      tables, cur_pos, window=window,
-                                     interpret=interp)
+                                     interpret=interpret)
 
 
 @partial(jax.jit, static_argnames=("threshold", "window", "interpret"))
 def paged_decode_sparse(q, k_pages, v_pages, tables, cur_pos, *,
-                        threshold: float, window: int = 0,
-                        interpret: bool | None = None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+                        threshold: float, window: int = 0, interpret: bool):
     keep = block_keep_mask(q, k_pages, tables, cur_pos,
                            threshold=threshold, window=window)
     return paged_decode_sparse_pallas(q, k_pages, v_pages, tables, cur_pos,
-                                      keep, window=window, interpret=interp)
+                                      keep, window=window,
+                                      interpret=interpret)

@@ -1,21 +1,21 @@
 """Cluster serving CLI: controller + N partition-worker processes.
 
-The multi-host-shaped deployment of the serving fleet: a controller process
-hosts the ``RequestQueue``, the routing policy, and the shared contention
-clock; each worker process wraps one ``PartitionEngine`` (its own model
-replica — the paper's per-partition weight replication) or a
-``SimulatedEngine`` (``--simulated``: phase timing and pool accounting
-only, no model execution).  Workers pin themselves to their
-``launch.mesh.make_partition_submesh`` group when the host has the devices
-for it and fall back to default placement otherwise, so the same command
-works on a laptop CPU and a pod slice.
+The multi-host-shaped deployment of the serving fleet: a controller hosts
+the ``RequestQueue``, the routing policy, and the shared contention clock;
+each worker wraps one ``PartitionEngine`` (its own model replica — the
+paper's per-partition weight replication) or a ``SimulatedEngine``
+(``--simulated``: phase timing and pool accounting only, no model
+execution).  Worker ``wid``'s real engine lives on local device
+``wid % n``.
 
   PYTHONPATH=src python -m repro.launch.cluster --arch qwen2-7b --smoke \
       --workers 4 --router shaping --transport mp --simulated
 
-``--transport loopback`` runs the identical protocol in-process
-(deterministic; the configuration the equivalence tests pin against the
-in-process ``EventScheduler``).
+``--transport loopback`` (the default) runs the identical protocol
+in-process (deterministic; the configuration the equivalence tests pin
+against the in-process ``EventScheduler``).  It is the only transport for
+real engines: ``mp`` and ``socket`` start one process per worker, and on a
+chip host only one process may hold the TPU.
 """
 from __future__ import annotations
 
@@ -27,6 +27,7 @@ from pathlib import Path
 
 from repro.configs import get_config
 from repro.core import hw
+from repro.launch.compile_cache import enable_compile_cache
 from repro.obs import (Tracer, format_summary, observe_phase_durations,
                        write_chrome)
 from repro.profiling import COST_MODELS
@@ -53,13 +54,16 @@ def build_cluster_args(ap: argparse.ArgumentParser) -> None:
                          "decode workers (N+M must equal the worker "
                          "count); default is an auto-rebalancing even "
                          "split")
-    ap.add_argument("--transport", default="mp", choices=list(TRANSPORTS),
-                    help="worker transport: 'mp' spawns one OS process per "
+    ap.add_argument("--transport", default="loopback",
+                    choices=list(TRANSPORTS),
+                    help="worker transport: 'loopback' runs the protocol "
+                         "in-process (deterministic; the only transport "
+                         "for real engines, one replica per local device); "
+                         "'mp' spawns one OS process per --simulated "
                          "worker over multiprocessing pipes; 'socket' "
                          "spawns the same workers dialing a TCP listener "
                          "(length-prefixed frames, the cross-host wire "
-                         "format; see docs/multi_host.md); 'loopback' runs "
-                         "the same protocol in-process (deterministic)")
+                         "format; see docs/multi_host.md)")
     ap.add_argument("--heartbeat-timeout", type=float, default=60.0,
                     help="wall seconds of silence before a worker is "
                          "declared dead and its requests fail over")
@@ -165,6 +169,12 @@ def validate_cluster_args(ap: argparse.ArgumentParser, args) -> None:
     """Parse-time validation of the shared cluster axis (both CLIs call
     this so a bad flag dies with ``ap.error`` instead of a downstream
     stack trace).  Rewrites ``args.pd_split`` from "N:M" to a tuple."""
+    if args.transport != "loopback" and not args.simulated:
+        ap.error(f"--transport {args.transport} starts one process per "
+                 "worker, and on a chip host only one process may hold the "
+                 "TPU: real engines run over --transport loopback (one "
+                 "replica per local device); --simulated workers may use "
+                 f"{args.transport}")
     if args.heartbeat_timeout <= 0:
         ap.error(f"--heartbeat-timeout must be > 0 wall seconds (got "
                  f"{args.heartbeat_timeout}); a non-positive timeout "
@@ -373,6 +383,7 @@ def run_cluster(*, arch: str, smoke: bool, workers: int, slots: int,
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")

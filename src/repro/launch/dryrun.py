@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from repro.configs import SHAPES, ARCH_IDS, applicable_shapes, get_config
 from repro.core import roofline
 from repro.launch import sharding as SH
-from repro.launch.mesh import make_production_mesh, batch_axes, mesh_context
+from repro.launch.mesh import make_production_mesh, batch_axes
 from repro.models import api as mapi
 from repro.models import pspec
 from repro.optim.adamw import adamw_init
@@ -171,7 +171,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                 arch, shape_name, mesh, partitions, accum=accum,
                 auto_kv=var.get("auto_kv", True))
             ss = var.get("seq_shard", False)
-            with mesh_context(mesh), pspec.axes(batch=bax, model_size=msz,
+            with jax.set_mesh(mesh), pspec.axes(batch=bax, model_size=msz,
                                                 seq_shard=ss):
                 jitted = jax.jit(fn, in_shardings=shards,
                                  donate_argnums=donate)
@@ -188,8 +188,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str,
                 "collectives_scan_aware": aware,
                 "collectives": roofline.parse_collectives(hlo_text),
                 "compile_s": round(t2 - t1, 2),
-                "cost_analysis": {k: float(roofline.cost_analysis_dict(
-                                               compiled).get(v, 0.0))
+                "cost_analysis": {k: float(compiled.cost_analysis().get(
+                                               v, 0.0))
                                   for k, v in [("flops", "flops"),
                                                ("bytes_accessed",
                                                 "bytes accessed")]},

@@ -17,11 +17,7 @@ happen only at sync points.
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5 exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: every mesh axis is implicitly "auto"
-    AxisType = None
+from jax.sharding import AxisType
 
 POD_CHIPS = 256          # 16 x 16 v5e pod slice
 DATA_AXIS = 16
@@ -30,18 +26,8 @@ N_PODS = 2
 
 
 def _mk(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(
         shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-
-
-def mesh_context(mesh):
-    """Ambient-mesh context manager across jax versions: ``jax.set_mesh``
-    where it exists, else the legacy ``Mesh``-as-context-manager form."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    return mesh
 
 
 def make_production_mesh(*, multi_pod: bool = False, partitions: int = 1):
@@ -62,14 +48,6 @@ def make_production_mesh(*, multi_pod: bool = False, partitions: int = 1):
         return _mk((N_PODS, partitions, inner, MODEL_AXIS),
                    ("pod", "part", "data", "model"))
     return _mk((partitions, inner, MODEL_AXIS), ("part", "data", "model"))
-
-
-def make_partition_submesh(partitions: int):
-    """The mesh a SINGLE partition group runs on between syncs: the paper's
-    per-partition synchronous group (multi-controller deployment mode)."""
-    if DATA_AXIS % partitions:
-        raise ValueError(f"partitions={partitions} must divide {DATA_AXIS}")
-    return _mk((DATA_AXIS // partitions, MODEL_AXIS), ("data", "model"))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

@@ -14,8 +14,9 @@ identical request load).
       --partitions 4 --stagger demand --clock event
 
 ``--cluster N`` runs the same load as a controller + N partition-worker
-cluster instead (one OS process per worker under ``--transport mp``; see
-``repro.launch.cluster`` for the routing/failover semantics).
+cluster instead (see ``repro.launch.cluster`` for the routing/failover
+semantics and the transports).  ``build_fleet`` is the in-process fleet
+``main`` runs; ``chip_smoke.py`` drives the same one on a TPU.
 
 ``--cost-model measured`` prices the demand-shaping rule from on-device
 wall-clock timings instead of the analytic decomposition; with
@@ -39,12 +40,14 @@ traffic — see ``docs/kv_quantization.md``.  Both require the paged pool
 from __future__ import annotations
 
 import argparse
+from dataclasses import dataclass
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
 from repro.core import hw
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api as mapi
 from repro.obs import (Tracer, format_summary, observe_phase_durations,
                        registry_from_engines, write_chrome)
@@ -55,7 +58,9 @@ from repro.serving import (CLOCKS, EventScheduler, PartitionEngine,
 from repro.serving.trace_sim import phase_balanced_bandwidth
 
 
-def main(argv=None):
+def parse_args(argv=None) -> argparse.Namespace:
+    """Parse and validate the serving CLI's flags (``ap.error`` on a bad
+    combination, before any model or device work)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -137,21 +142,27 @@ def main(argv=None):
             if val is not None:
                 ap.error(f"{flag} applies to the in-process fleet and is "
                          f"ignored by --cluster; {hint}")
-        from repro.launch.cluster import run_cluster
-        ctl, _ = run_cluster(
-            arch=args.arch, smoke=args.smoke, workers=args.cluster,
-            slots=args.batch, prompt_len=args.prompt_len, gen=args.gen,
-            n_requests=args.requests, router=args.router,
-            transport=args.transport, simulated=args.simulated,
-            block_size=args.block_size, dense=args.dense,
-            heartbeat_timeout=args.heartbeat_timeout,
-            max_queue=args.max_queue, deadline=args.deadline,
-            cost_model=args.cost_model, profile=args.profile,
-            pd_split=args.pd_split, prefix_cache=args.prefix_cache,
-            kv_dtype=args.kv_dtype, sparse_threshold=args.sparse_threshold,
-            trace=args.trace)
-        return [r.tokens for r in ctl.queue.completed]
+    return args
 
+
+@dataclass
+class Fleet:
+    """The in-process serving fleet ``main`` runs: P engines sharing one
+    set of params and one request queue, driven by ``sched``."""
+    cfg: object
+    api: object
+    params: object
+    engines: list
+    queue: RequestQueue
+    sched: object
+    bandwidth: float
+    cost_model: object
+    tracer: object
+
+
+def build_fleet(args: argparse.Namespace) -> Fleet:
+    """Build the engines, queue the request load and assemble the
+    scheduler for parsed serving ``args`` (not ``--cluster``)."""
     cfg = get_config(args.arch, smoke=args.smoke)
     P = args.partitions if args.partitions is not None else 1
     args.stagger = args.stagger if args.stagger is not None else "uniform"
@@ -177,7 +188,9 @@ def main(argv=None):
     # Built BEFORE the request load so admission control can probe the
     # fleet's prefix caches (a hit-eligible request is priced post-hit).
     api = mapi.build(cfg)
-    params = api.init(jax.random.PRNGKey(0))
+    # jitted: eager init would materialize each stacked leaf in float32
+    # before the cast
+    params = jax.jit(api.init)(jax.random.PRNGKey(0))
     paged = (cfg.family != "encdec") and not args.dense
     # one shared jitted fn per phase: same shapes across engines -> one
     # compiled executable for the whole fleet
@@ -250,6 +263,33 @@ def main(argv=None):
                            bandwidth=bandwidth, clock=args.clock)
     if tracer is not None:
         sched.attach_tracer(tracer)
+    return Fleet(cfg, api, params, engines, queue, sched, bandwidth,
+                 cost_model, tracer)
+
+
+def main(argv=None):
+    enable_compile_cache()
+    args = parse_args(argv)
+    if args.cluster is not None:
+        from repro.launch.cluster import run_cluster
+        ctl, _ = run_cluster(
+            arch=args.arch, smoke=args.smoke, workers=args.cluster,
+            slots=args.batch, prompt_len=args.prompt_len, gen=args.gen,
+            n_requests=args.requests, router=args.router,
+            transport=args.transport, simulated=args.simulated,
+            block_size=args.block_size, dense=args.dense,
+            heartbeat_timeout=args.heartbeat_timeout,
+            max_queue=args.max_queue, deadline=args.deadline,
+            cost_model=args.cost_model, profile=args.profile,
+            pd_split=args.pd_split, prefix_cache=args.prefix_cache,
+            kv_dtype=args.kv_dtype, sparse_threshold=args.sparse_threshold,
+            trace=args.trace)
+        return [r.tokens for r in ctl.queue.completed]
+
+    f = build_fleet(args)
+    cfg, engines, queue, sched = f.cfg, f.engines, f.queue, f.sched
+    cost_model, tracer, bandwidth = f.cost_model, f.tracer, f.bandwidth
+    P, slots = len(engines), args.batch
     m = sched.run()
     s = m.summary()
     print(f"serve: {cfg.name} P={P} stagger={args.stagger} "

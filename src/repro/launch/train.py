@@ -24,6 +24,7 @@ from repro.configs import SMOKE_SHAPES, SHAPES, get_config
 from repro.configs.base import ShapeCell
 from repro.core.partitioning import PartitionConfig
 from repro.data.pipeline import synth_lm_batch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import api as mapi
 from repro.optim.adamw import adamw_init
 from repro.runtime import steps as RS
@@ -31,6 +32,7 @@ from repro.runtime.partition_runtime import PartitionRuntime
 
 
 def main(argv=None):
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true",

@@ -14,6 +14,7 @@ from __future__ import annotations
 
 import math
 from dataclasses import dataclass
+from functools import partial
 from typing import List
 
 import jax
@@ -294,22 +295,26 @@ def init_cnn(key, name, img=None, dtype=jnp.float32):
     return params
 
 
-def _conv2d(x, w, stride, conv_impl="xla"):
+def _conv2d(x, w, stride, conv_impl="xla", interpret=False):
     if conv_impl == "pallas":
         from repro.kernels.conv2d import ops as conv_ops
-        return conv_ops.conv2d(x, w, stride=stride, padding="SAME")
+        return conv_ops.conv2d(x, w, stride=stride, padding="SAME",
+                               interpret=interpret)
     return lax.conv_general_dilated(
         x, w, (stride, stride), "SAME",
         dimension_numbers=("NHWC", "HWIO", "NHWC"))
 
 
-def apply_cnn(params, name, x, conv_impl="xla"):
-    """x: (B, H, W, 3) -> logits (B, 1000)."""
+def apply_cnn(params, name, x, conv_impl="xla", interpret=False):
+    """x: (B, H, W, 3) -> logits (B, 1000).  ``conv_impl="pallas"`` runs
+    the convolutions through the Pallas kernel, interpreted when
+    ``interpret`` is set (any backend) and compiled for the TPU otherwise."""
     ops = CNN_OPS[name]()
+    conv = partial(_conv2d, conv_impl=conv_impl, interpret=interpret)
     for op, p in zip(ops, params):
         kind = op["kind"]
         if kind == "conv":
-            x = _conv2d(x, p["w"], op["s"], conv_impl)
+            x = conv(x, p["w"], op["s"])
             x = jax.nn.relu(x * p["scale"] + p["shift"])
         elif kind == "maxpool":
             x = lax.reduce_window(
@@ -325,21 +330,21 @@ def apply_cnn(params, name, x, conv_impl="xla"):
                 x = jax.nn.relu(x)
             x = x.reshape(x.shape[0], 1, 1, -1)
         elif kind == "inception":
-            b1 = jax.nn.relu(_conv2d(x, p["b1"], 1, conv_impl))
-            b3 = jax.nn.relu(_conv2d(
-                jax.nn.relu(_conv2d(x, p["b3r"], 1, conv_impl)),
-                p["b3"], 1, conv_impl))
-            b5 = jax.nn.relu(_conv2d(
-                jax.nn.relu(_conv2d(x, p["b5r"], 1, conv_impl)),
-                p["b5"], 1, conv_impl))
+            b1 = jax.nn.relu(conv(x, p["b1"], 1))
+            b3 = jax.nn.relu(conv(
+                jax.nn.relu(conv(x, p["b3r"], 1)),
+                p["b3"], 1))
+            b5 = jax.nn.relu(conv(
+                jax.nn.relu(conv(x, p["b5r"], 1)),
+                p["b5"], 1))
             bp = lax.reduce_window(
                 x, -jnp.inf, lax.max, (1, 3, 3, 1), (1, 1, 1, 1), "SAME")
-            bp = jax.nn.relu(_conv2d(bp, p["bp"], 1, conv_impl))
+            bp = jax.nn.relu(conv(bp, p["bp"], 1))
             x = jnp.concatenate([b1, b3, b5, bp], axis=-1)
         elif kind == "resblock":
-            h = jax.nn.relu(_conv2d(x, p["c1"], op["s"], conv_impl))
-            h = jax.nn.relu(_conv2d(h, p["c3"], 1, conv_impl))
-            h = _conv2d(h, p["cout"], 1, conv_impl)
-            sc = _conv2d(x, p["proj"], op["s"], conv_impl) if "proj" in p else x
+            h = jax.nn.relu(conv(x, p["c1"], op["s"]))
+            h = jax.nn.relu(conv(h, p["c3"], 1))
+            h = conv(h, p["cout"], 1)
+            sc = conv(x, p["proj"], op["s"]) if "proj" in p else x
             x = jax.nn.relu(h + sc)
     return x.reshape(x.shape[0], -1)

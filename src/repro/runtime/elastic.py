@@ -15,11 +15,6 @@ from __future__ import annotations
 
 import jax
 
-try:  # jax >= 0.5; remesh plans carry explicit axis types there
-    from jax.sharding import AxisType  # noqa: F401
-except ImportError:  # older jax: axis types are implicit, plans still valid
-    AxisType = None
-
 from repro.launch import sharding as SH
 
 
@@ -35,34 +30,6 @@ def plan_mesh(n_devices: int, model_axis: int = 16, prefer_model: bool = True):
         raise ValueError(f"cannot mesh {n_devices} devices")
     usable = data * m
     return (data, m), usable
-
-
-def submesh_plan(n_local_devices: int, partitions: int, *,
-                 data_axis: int = 16, model_axis: int = 16):
-    """The (data, model) grid one cluster worker should pin, or None.
-
-    This is the elastic worker join path (``serving.cluster.worker``
-    builds every engine — initial fleet and mid-run joiners alike —
-    through it): a worker serving one of ``partitions`` compute partitions
-    pins the full per-partition synchronous group when its host has the
-    devices for it; a host that lost chips pins the largest ``plan_mesh``
-    grid its survivors support *with the model axis preserved* (param
-    shardings stay valid — a narrower data axis just means fewer batch
-    shards), so it re-joins degraded rather than not at all.  None means
-    default placement: partitions that don't divide the data axis, or a
-    host where even one model group does not fit (every CPU dev box).
-    """
-    if partitions <= 1 or data_axis % partitions:
-        return None
-    full = (data_axis // partitions, model_axis)
-    if n_local_devices >= full[0] * full[1]:
-        return full
-    if n_local_devices < model_axis:
-        return None
-    (data, m), _usable = plan_mesh(n_local_devices, model_axis=model_axis)
-    if m != model_axis:
-        return None
-    return (min(data, full[0]), m)
 
 
 def remesh_state(state, cfg, old_mesh, new_mesh):

@@ -9,8 +9,8 @@ The in-process fleet turned into a controller + N partition-worker cluster:
     validation) and a real ``multiprocessing`` pipe transport, one worker
     process per ``WorkerSpec``;
   * ``worker``    — ``WorkerRuntime`` adapts one ``PartitionEngine`` /
-    ``SimulatedEngine`` to the protocol; real engines pin themselves to a
-    ``launch.mesh.make_partition_submesh`` group when devices allow;
+    ``SimulatedEngine`` to the protocol; worker ``wid``'s real engine
+    lives on local device ``wid % n``;
   * ``controller``— the ``RequestQueue`` + routing policies (round_robin /
     shortest_backlog / shaping / pd) + heartbeat-timeout failover,
     driving the shared ``core.timeline`` contention clock.  The ``pd``
@@ -49,8 +49,7 @@ def make_worker_specs(arch: str, n_workers: int, *, smoke: bool = True,
                       prefix_cache: bool = False, kv_dtype: str = "fp32",
                       sparse_threshold: float = 0.0) -> List[WorkerSpec]:
     """One spec per worker; the fleet splits ``peak_flops_total`` evenly
-    (the paper's 1/P compute split) and each worker learns the cluster
-    width for submesh pinning.  ``cost_model`` / ``profile`` pick each
+    (the paper's 1/P compute split).  ``cost_model`` / ``profile`` pick each
     worker's phase-pricing source (see ``WorkerSpec``); ``prefix_cache``
     turns on each worker's KV-pool prefix index (per-worker caches — the
     pool is worker-local, so hits depend on the router landing shared
@@ -62,8 +61,7 @@ def make_worker_specs(arch: str, n_workers: int, *, smoke: bool = True,
                        max_len=max_len,
                        peak_flops=peak_flops_total / n_workers,
                        engine=engine, wave_only=wave_only,
-                       block_size=block_size, paged=paged,
-                       partitions=n_workers, seed=seed,
+                       block_size=block_size, paged=paged, seed=seed,
                        cost_model=cost_model, profile=profile,
                        prefix_cache=prefix_cache, kv_dtype=kv_dtype,
                        sparse_threshold=sparse_threshold)

@@ -8,16 +8,14 @@ calls ``handle`` in-process, ``worker_main`` runs it as a subprocess
 recv/handle/send loop over a multiprocessing pipe.
 
 ``WorkerSpec`` is the picklable recipe a worker process builds its engine
-from (the controller never ships live objects across the boundary).  Real
-engines pin themselves to a ``launch.mesh.make_partition_submesh`` group
-when the host has enough devices — the paper's per-partition synchronous
-group — and fall back to the default (single-)device placement otherwise,
-so the cluster runs unchanged on a laptop CPU and on a pod slice.
+from (the controller never ships live objects across the boundary).  A
+real engine for worker ``wid`` holds its params and KV pages on local
+device ``wid % n`` for its whole run, so one process can serve one replica
+per chip of its host.
 """
 from __future__ import annotations
 
 import traceback
-from contextlib import nullcontext
 from dataclasses import dataclass
 from typing import Optional
 
@@ -159,7 +157,6 @@ class WorkerSpec:
     wave_only: bool = False
     block_size: int = 16
     paged: Optional[bool] = None
-    partitions: int = 1          # submesh group count (real engines)
     seed: int = 0
     cost_model: str = "analytic"  # phase pricing: "analytic" | "measured"
     profile: Optional[str] = None  # saved calibration profile (replay)
@@ -168,24 +165,12 @@ class WorkerSpec:
     sparse_threshold: float = 0.0  # blockwise-sparse attention cutoff
 
 
-def _partition_mesh(spec: WorkerSpec):
-    """Pin the worker to the mesh ``runtime.elastic.submesh_plan`` picks
-    for this host: the full ``make_partition_submesh`` group when the
-    devices are there, a narrower data axis when the host lost chips (the
-    elastic re-join path), or default placement (CPU dev boxes).  Returns
-    a context manager either way."""
+def replica_device(wid: int):
+    """The local device worker ``wid``'s real engine lives on."""
     import jax
 
-    from repro.launch import mesh as M
-    from repro.runtime.elastic import submesh_plan
-
-    plan = submesh_plan(len(jax.devices()), spec.partitions,
-                        data_axis=M.DATA_AXIS, model_axis=M.MODEL_AXIS)
-    if plan is None:
-        return nullcontext()
-    if plan == (M.DATA_AXIS // spec.partitions, M.MODEL_AXIS):
-        return M.mesh_context(M.make_partition_submesh(spec.partitions))
-    return M.mesh_context(M.make_host_mesh(*plan))
+    devices = jax.local_devices()
+    return devices[wid % len(devices)]
 
 
 def build_engine(spec: WorkerSpec) -> EngineBase:
@@ -220,32 +205,33 @@ def build_engine(spec: WorkerSpec) -> EngineBase:
     if spec.engine != "real":
         raise ValueError(f"unknown engine kind {spec.engine!r}")
     import jax
+    from jax.sharding import SingleDeviceSharding
 
     from repro.models import api as mapi
     from repro.serving.engine import PartitionEngine
 
-    with _partition_mesh(spec):
-        api = mapi.build(cfg)
-        params = api.init(jax.random.PRNGKey(spec.seed))
-        return PartitionEngine(cfg, api, params, paged=spec.paged,
-                               seed=spec.seed, **kw)
+    device = replica_device(spec.wid)
+    api = mapi.build(cfg)
+    # jitted: eager init would materialize each stacked leaf in float32
+    # before the cast; out_shardings builds the params on the device
+    params = jax.jit(api.init, out_shardings=SingleDeviceSharding(device))(
+        jax.random.PRNGKey(spec.seed))
+    return PartitionEngine(cfg, api, params, paged=spec.paged,
+                           seed=spec.seed, device=device, **kw)
 
 
 def worker_main(conn, spec: WorkerSpec) -> None:
     """Subprocess entry: build the engine, say Hello, then serve the
     request/reply loop until Shutdown (or the pipe closes)."""
-    mesh_ctx = _partition_mesh(spec) if spec.engine == "real" else \
-        nullcontext()
-    with mesh_ctx:
-        rt = WorkerRuntime(build_engine(spec))
-        conn.send(P.encode(rt.hello()))
-        while True:
-            try:
-                msg = P.decode(conn.recv())
-            except (EOFError, OSError):
-                break  # controller went away
-            reply = rt.handle(msg)
-            conn.send(P.encode(reply))
-            if isinstance(reply, P.Bye):
-                break
+    rt = WorkerRuntime(build_engine(spec))
+    conn.send(P.encode(rt.hello()))
+    while True:
+        try:
+            msg = P.decode(conn.recv())
+        except (EOFError, OSError):
+            break  # controller went away
+        reply = rt.handle(msg)
+        conn.send(P.encode(reply))
+        if isinstance(reply, P.Bye):
+            break
     conn.close()

@@ -59,7 +59,7 @@ from repro.profiling.cost_model import (AnalyticCostModel,  # noqa: F401
 from repro.profiling.timer import shape_key
 from repro.serving.kv_pool import (KV_DTYPES, NULL_BLOCK, BlockPool,
                                    ChainAlloc, PoolExhausted,
-                                   kv_dtype_supported)
+                                   write_prefix_pages)
 from repro.serving.queue import Request
 
 # model families whose per-sequence state does not live (only) in KV blocks:
@@ -137,10 +137,6 @@ class EngineBase:
         if kv_dtype not in KV_DTYPES:
             raise ValueError(f"unknown kv_dtype {kv_dtype!r}: expected one "
                              f"of {KV_DTYPES}")
-        if not kv_dtype_supported(kv_dtype):
-            raise ValueError(
-                f"kv_dtype {kv_dtype!r} is not supported by this jax build "
-                "(no float8_e4m3fn dtype); use 'int8' or 'fp32'")
         if not 0.0 <= sparse_threshold < 1.0:
             raise ValueError("sparse_threshold must be in [0, 1) — it is a "
                              "per-block attention-mass cutoff, and >= 1 "
@@ -631,6 +627,12 @@ class EngineBase:
 # ---------------------------------------------------------------------------
 
 
+def _scatter_prefix(pages, k, v, src, tables):
+    """Scatter batch rows ``src`` of dense K/V ``(L, B, S, Hkv, D)`` into
+    the pool blocks ``tables`` names."""
+    return write_prefix_pages(pages, k[:, src], v[:, src], tables)
+
+
 class PartitionEngine(EngineBase):
     """Runs the actual model.  ``params`` may be shared across engines
     in-process (they are read-only during serving); on hardware each
@@ -654,7 +656,7 @@ class PartitionEngine(EngineBase):
                  wave_only: bool = False,
                  cost_model: Optional[CostModel] = None,
                  prefix_cache: bool = False, kv_dtype: str = "fp32",
-                 sparse_threshold: float = 0.0):
+                 sparse_threshold: float = 0.0, device=None):
         super().__init__(cfg, slots=slots, max_len=max_len, pid=pid,
                          peak_flops=peak_flops, block_size=block_size,
                          pool_blocks=pool_blocks, wave_only=wave_only,
@@ -700,6 +702,12 @@ class PartitionEngine(EngineBase):
         self._prefill_uniform_fn = prefill_uniform_fn or jax.jit(
             lambda p, b, ml: api.prefill(p, b, max_len=ml),
             static_argnames=("ml",))
+        # one jitted scatter per engine: donating the pool updates it in
+        # place instead of holding two copies of it at every install
+        self._write_pages = jax.jit(_scatter_prefix, donate_argnums=(0,))
+        # where this engine's arrays live (None: JAX's default device); the
+        # caller places ``params`` there, the engine its lazily built pool
+        self.device = device
         self.cache = None          # dense mode / encdec
         self.pages = None          # paged mode: k_pages/v_pages/ssm arrays
         self._last_tok = None
@@ -776,16 +784,19 @@ class PartitionEngine(EngineBase):
     def _ensure_pages(self) -> None:
         """Lazily initialise the paged pool arrays (first prefill OR first
         KV import on a fresh decode-pool engine)."""
+        import jax
+
         from repro.serving import kv_pool as KV
 
         if self.pages is None:
-            self.pages = KV.init_pages(self.cfg, self.pool.n_blocks,
-                                       self.block_size,
-                                       kv_dtype=self.kv_dtype)
-            if self._has_ssm():
-                st = self.api.init_cache(self.slots, 1)
-                self.pages["ssm_state"] = st["ssm_state"]
-                self.pages["ssm_conv"] = st["ssm_conv"]
+            with jax.default_device(self.device):
+                self.pages = KV.init_pages(self.cfg, self.pool.n_blocks,
+                                           self.block_size,
+                                           kv_dtype=self.kv_dtype)
+                if self._has_ssm():
+                    st = self.api.init_cache(self.slots, 1)
+                    self.pages["ssm_state"] = st["ssm_state"]
+                    self.pages["ssm_conv"] = st["ssm_conv"]
 
     def _install_paged(self, cache, rows: List[int],
                        src_rows: Optional[List[int]] = None) -> None:
@@ -794,8 +805,6 @@ class PartitionEngine(EngineBase):
         SSM state into the per-slot arrays.  One scatter per pool array
         regardless of how many slots are installed."""
         import jax.numpy as jnp
-
-        from repro.serving import kv_pool as KV
 
         self._ensure_pages()
         src = list(src_rows if src_rows is not None else rows)
@@ -808,14 +817,13 @@ class PartitionEngine(EngineBase):
                 # divert their rewrite to the null block so a reference-
                 # shared block is never written
                 tables[j, :self.slot_shared[i]] = NULL_BLOCK
-            src_a = jnp.asarray(src, jnp.int32)
             sub = {"k_pages": self.pages["k_pages"],
                    "v_pages": self.pages["v_pages"]}
             if "k_scales" in self.pages:
                 sub["k_scales"] = self.pages["k_scales"]
                 sub["v_scales"] = self.pages["v_scales"]
-            self.pages.update(KV.write_prefix_pages(
-                sub, cache["k"][:, src_a], cache["v"][:, src_a],
+            self.pages.update(self._write_pages(
+                sub, cache["k"], cache["v"], jnp.asarray(src, jnp.int32),
                 jnp.asarray(tables)))
         if self._has_ssm():
             rows_a = jnp.asarray(rows, jnp.int32)

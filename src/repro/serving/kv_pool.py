@@ -352,17 +352,6 @@ KV_DTYPE_BYTES: Dict[str, int] = {"fp32": 4, "int8": 1, "fp8": 1}
 KV_DTYPES = tuple(KV_DTYPE_BYTES)
 
 
-def kv_dtype_supported(kv_dtype: str) -> bool:
-    """fp8 needs a jax new enough to ship ``float8_e4m3fn``; fp32/int8 are
-    always available."""
-    if kv_dtype not in KV_DTYPE_BYTES:
-        return False
-    if kv_dtype != "fp8":
-        return True
-    import jax.numpy as jnp
-    return hasattr(jnp, "float8_e4m3fn")
-
-
 def _kv_qspec(kv_dtype: str):
     """(packed jnp dtype, qmax) for a quantized layout name."""
     import jax.numpy as jnp
@@ -370,10 +359,6 @@ def _kv_qspec(kv_dtype: str):
     if kv_dtype == "int8":
         return jnp.int8, 127.0
     if kv_dtype == "fp8":
-        if not kv_dtype_supported("fp8"):
-            raise ValueError(
-                "kv_dtype='fp8' needs jax.numpy.float8_e4m3fn, which this "
-                "jax build does not provide; use 'int8'")
         return jnp.float8_e4m3fn, float(jnp.finfo(jnp.float8_e4m3fn).max)
     raise ValueError(f"unknown quantized kv_dtype {kv_dtype!r}; "
                      f"expected one of {sorted(KV_DTYPE_BYTES)}")

@@ -400,3 +400,39 @@ def test_loopback_transport_is_strict_request_reply():
     assert isinstance(tp.recv(0), P.Hello)
     with pytest.raises(RuntimeError, match="request/reply"):
         tp.recv(0)
+
+
+def test_real_replicas_each_hold_their_own_device():
+    """Worker ``wid``'s real engine keeps its params and KV pages on local
+    device ``wid % n`` for the whole run (four host devices, in a child so
+    the device count does not leak into this process)."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    from pathlib import Path
+
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.serving import RequestQueue, make_cluster, make_worker_specs
+        q = RequestQueue()
+        rng = np.random.default_rng(0)
+        for _ in range(10):
+            q.submit(rng.integers(1, 100, size=(8,)).astype(np.int32), 4)
+        specs = make_worker_specs("qwen2-7b", 5, slots=2, max_len=32,
+                                  engine="real")
+        ctl = make_cluster(specs, q, transport="loopback")
+        ctl.run()
+        for wid, rt in sorted(ctl.transport.runtimes.items()):
+            leaves = jax.tree.leaves((rt.engine.params, rt.engine.pages))
+            print(wid, sorted({d.id for a in leaves for d in a.devices()}))
+        print("completed", len(q.completed))
+    """)
+    src = Path(__file__).resolve().parents[1] / "src"
+    env = dict(os.environ, PYTHONPATH=str(src), JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    r = subprocess.run([sys.executable, "-c", code], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr
+    assert r.stdout.split("\n")[:6] == [
+        "0 [0]", "1 [1]", "2 [2]", "3 [3]", "4 [0]", "completed 10"]

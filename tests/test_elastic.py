@@ -1,18 +1,15 @@
 """repro.runtime.elastic: remesh planning + global-batch preservation.
 
-The drift this PR fixed: the cluster worker's join path now builds every
-engine through ``submesh_plan`` (degraded hosts re-join with a narrower
-data axis instead of not at all), and ``PartitionRuntime`` re-derives its
-grad-accumulation factor through ``accum_for_batch`` on every membership
-change — absolute from the initial fleet, so drop-then-replace lands back
-exactly at the original accum.
+``PartitionRuntime`` re-derives its grad-accumulation factor through
+``accum_for_batch`` on every membership change — absolute from the initial
+fleet, so drop-then-replace lands back exactly at the original accum.
 """
 import jax
 import jax.numpy as jnp
 import pytest
 
 from repro.core.partitioning import PartitionConfig
-from repro.runtime.elastic import accum_for_batch, plan_mesh, submesh_plan
+from repro.runtime.elastic import accum_for_batch, plan_mesh
 from repro.runtime.partition_runtime import PartitionRuntime
 
 # ---------------------------------------------------------------------------
@@ -30,26 +27,6 @@ def test_plan_mesh_prefers_model_axis():
     assert plan_mesh(7) == ((7, 1), 7)
     with pytest.raises(ValueError, match="cannot mesh"):
         plan_mesh(0)
-
-
-def test_submesh_plan_full_group():
-    # 4 partitions over data_axis 16: each worker pins (4, 16) = 64 devs
-    assert submesh_plan(64, 4) == (4, 16)
-    assert submesh_plan(128, 4) == (4, 16)  # surplus devices: same group
-
-
-def test_submesh_plan_degraded_host_narrows_data_axis():
-    # host lost chips but still fits whole model groups: data axis shrinks
-    assert submesh_plan(32, 4) == (2, 16)
-    assert submesh_plan(16, 4) == (1, 16)
-
-
-def test_submesh_plan_default_placement_cases():
-    assert submesh_plan(8, 4) is None       # can't fit one model group
-    assert submesh_plan(64, 1) is None      # single partition: no pinning
-    assert submesh_plan(64, 3) is None      # 3 doesn't divide data_axis=16
-    assert submesh_plan(24, 4) is None      # survivors only mesh at m=8
-    assert submesh_plan(0, 4) is None
 
 
 # ---------------------------------------------------------------------------

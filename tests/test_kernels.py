@@ -25,7 +25,7 @@ TOL = {jnp.float32: dict(rtol=2e-4, atol=2e-4),
 def test_matmul(M, K, N, bm, bk, bn, dtype):
     a = jax.random.normal(jax.random.PRNGKey(0), (M, K), jnp.float32).astype(dtype)
     b = jax.random.normal(jax.random.PRNGKey(1), (K, N), jnp.float32).astype(dtype)
-    out = mm_ops.matmul(a, b, bm=bm, bk=bk, bn=bn)
+    out = mm_ops.matmul(a, b, bm=bm, bk=bk, bn=bn, interpret=True)
     ref = mm_ref.matmul(a, b)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOL[dtype])
@@ -44,7 +44,7 @@ def test_flash_attention(B, S, Hq, Hkv, D, causal, window, dtype):
     k = jax.random.normal(ks[1], (B, S, Hkv, D), jnp.float32).astype(dtype)
     v = jax.random.normal(ks[2], (B, S, Hkv, D), jnp.float32).astype(dtype)
     out = fa_ops.flash_attention(q, k, v, causal=causal, window=window,
-                                 bq=64, bk=64)
+                                 bq=64, bk=64, interpret=True)
     ref = fa_ref.attention(q, k, v, causal=causal, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOL[dtype])
@@ -61,7 +61,8 @@ def test_conv2d(N, H, W, C, K, kh, stride, dtype):
     ks = jax.random.split(jax.random.PRNGKey(0), 2)
     x = jax.random.normal(ks[0], (N, H, W, C), jnp.float32).astype(dtype)
     w = (jax.random.normal(ks[1], (kh, kh, C, K), jnp.float32) * 0.1).astype(dtype)
-    out = conv_ops.conv2d(x, w, stride=stride, padding="SAME", tk=K)
+    out = conv_ops.conv2d(x, w, stride=stride, padding="SAME", tk=K,
+                          interpret=True)
     ref = conv_ref.conv2d(x, w, stride=stride)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOL[dtype])
@@ -82,7 +83,8 @@ def test_flash_decode(B, S, Hq, Hkv, D, cur, window, bk, dtype):
     kc = jax.random.normal(ks[1], (B, S, Hkv, D), jnp.float32).astype(dtype)
     vc = jax.random.normal(ks[2], (B, S, Hkv, D), jnp.float32).astype(dtype)
     pos = jnp.asarray(cur, jnp.int32)
-    out = fd_ops.flash_decode(q, kc, vc, pos, window=window, bk=bk)
+    out = fd_ops.flash_decode(q, kc, vc, pos, window=window, bk=bk,
+                              interpret=True)
     ref = fd_ref.decode_attention(q, kc, vc, pos, window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), **TOL[dtype])
@@ -98,7 +100,7 @@ def test_flash_decode_matches_model_decode():
     kc = jax.random.normal(ks[1], (B, S, Hkv, D))
     vc = jax.random.normal(ks[2], (B, S, Hkv, D))
     pos = jnp.asarray(77, jnp.int32)
-    out_k = fd_ops.flash_decode(q[:, 0], kc, vc, pos, bk=64)
+    out_k = fd_ops.flash_decode(q[:, 0], kc, vc, pos, bk=64, interpret=True)
     out_m = model_decode(q, kc, vc, pos)[:, 0]
     np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_m),
                                rtol=2e-4, atol=2e-4)
@@ -135,7 +137,8 @@ def test_paged_decode_attention(B, Hq, Hkv, D, bs, window, dtype):
     q = jax.random.normal(jax.random.PRNGKey(5), (B, Hq, D),
                           jnp.float32).astype(dtype)
     cur = jnp.asarray([l - 1 for l in lens], jnp.int32)
-    out = pa_ops.paged_decode(q, kp, vp, tables, cur, window=window)
+    out = pa_ops.paged_decode(q, kp, vp, tables, cur, window=window,
+                              interpret=True)
     ref = pa_ref.paged_decode_attention(q, kp, vp, tables, cur,
                                         window=window)
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -200,7 +203,7 @@ def test_paged_decode_quant(B, Hq, Hkv, D, bs, window):
     q = jax.random.normal(jax.random.PRNGKey(5), (B, Hq, D))
     cur = jnp.asarray([l - 1 for l in lens], jnp.int32)
     out = pa_ops.paged_decode_quant(q, kq, vq, ks, vs, tables, cur,
-                                    window=window)
+                                    window=window, interpret=True)
     ref = pa_ref.paged_decode_attention_quant(q, kq, vq, ks, vs, tables,
                                               cur, window=window)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -249,7 +252,8 @@ def test_paged_decode_sparse(B, Hq, Hkv, D, bs, threshold, window):
     q = jax.random.normal(jax.random.PRNGKey(5), (B, Hq, D))
     cur = jnp.asarray([l - 1 for l in lens], jnp.int32)
     out = pa_ops.paged_decode_sparse(q, kp, vp, tables, cur,
-                                     threshold=threshold, window=window)
+                                     threshold=threshold, window=window,
+                                     interpret=True)
     ref = pa_ref.paged_decode_attention_sparse(q, kp, vp, tables, cur,
                                                threshold=threshold,
                                                window=window)
@@ -273,7 +277,8 @@ def test_sparse_threshold_zero_is_dense():
                                                  threshold=0.0)
     ref_d = pa_ref.paged_decode_attention(q, kp, vp, tables, cur)
     np.testing.assert_array_equal(np.asarray(ref_s), np.asarray(ref_d))
-    out_s = pa_ops.paged_decode_sparse(q, kp, vp, tables, cur, threshold=0.0)
+    out_s = pa_ops.paged_decode_sparse(q, kp, vp, tables, cur, threshold=0.0,
+                                       interpret=True)
     np.testing.assert_allclose(np.asarray(out_s), np.asarray(ref_d),
                                rtol=2e-4, atol=2e-4)
 

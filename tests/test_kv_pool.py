@@ -9,7 +9,7 @@ from collections import Counter
 
 import pytest
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from repro.serving.kv_pool import NULL_BLOCK, BlockPool, PoolExhausted
 
 

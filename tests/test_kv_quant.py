@@ -133,15 +133,9 @@ def test_unknown_kv_dtype_rejected():
         _sim(cfg, kv_dtype="int4")
 
 
-def test_fp8_requires_jax_support():
-    from repro.serving.kv_pool import kv_dtype_supported
-
+def test_fp8_is_accepted():
     cfg = get_config("qwen2-7b", smoke=True)
-    if kv_dtype_supported("fp8"):
-        assert _sim(cfg, kv_dtype="fp8").kv_dtype == "fp8"
-    else:
-        with pytest.raises(ValueError, match="not supported by this jax"):
-            _sim(cfg, kv_dtype="fp8")
+    assert _sim(cfg, kv_dtype="fp8").kv_dtype == "fp8"
 
 
 def test_sparse_threshold_domain_rejected():

@@ -19,7 +19,7 @@ statistical contracts the load harness sells:
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.serving.loadgen import (ARRIVALS, LengthMix, SloSpec,
                                    bursty_arrivals, bursty_rates,

@@ -420,6 +420,17 @@ def test_cluster_cli_rejects_bad_flags(extra):
         _cluster_main(extra)
 
 
+@pytest.mark.parametrize("transport", ["mp", "socket"])
+def test_cluster_cli_refuses_real_engines_over_processes(transport, capsys):
+    """mp/socket start one process per worker; real engines (no
+    --simulated) would each load the TPU, so the CLI points to loopback."""
+    with pytest.raises(SystemExit):
+        _cluster_main(["--transport", transport])
+    assert "--transport loopback" in capsys.readouterr().err
+    _cluster_main(["--transport", transport, "--simulated", "--workers",
+                   "2", "--requests", "2", "--heartbeat-timeout", "120"])
+
+
 def test_serve_cli_rejects_pd_without_cluster():
     from repro.launch.serve import main
     with pytest.raises(SystemExit):

@@ -12,7 +12,6 @@ import pytest
 
 from repro.configs import get_config
 from repro.configs.base import ShapeCell
-from repro.core.roofline import cost_analysis_dict
 from repro.core.traffic import cell_flops, model_params
 from repro.models import api as mapi
 from repro.models import transformer as TF
@@ -36,9 +35,7 @@ def test_analytic_flops_vs_cost_analysis(arch, tol):
         return TF.loss_fn(p, cfg, batch, loss_chunk=S)[0]
 
     comp = jax.jit(jax.grad(fwd_loss)).lower(params, specs).compile()
-    # cost_analysis_dict: on jax<=0.4.x cost_analysis() returns [dict], not
-    # dict — the analytic counts themselves match within the stated tols.
-    measured = float(cost_analysis_dict(comp).get("flops", 0.0))
+    measured = float(comp.cost_analysis().get("flops", 0.0))
     analytic = cell_flops(cfg, shape)["total"]
     assert measured > 0
     ratio = analytic / measured

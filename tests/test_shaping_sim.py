@@ -1,7 +1,7 @@
 """Paper-reproduction gates + hypothesis property tests for the simulator."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.shaping_sim import (Task, maxmin_fair, partition_sweep,
                                     simulate, tasks_from_traces)

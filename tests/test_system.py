@@ -33,9 +33,8 @@ def test_dryrun_small_fleet_subprocess():
         import jax
         from repro.configs import get_config
         from repro.configs.base import ShapeCell
-        from repro.core.roofline import cost_analysis_dict
         from repro.launch import sharding as SH
-        from repro.launch.mesh import batch_axes, make_host_mesh, mesh_context
+        from repro.launch.mesh import batch_axes, make_host_mesh
         from repro.models import api as mapi, pspec
         from repro.optim.adamw import adamw_init
         from repro.runtime import steps as RS
@@ -53,13 +52,13 @@ def test_dryrun_small_fleet_subprocess():
         specs = api.input_specs(shape)
         b_sh = SH.batch_shardings(specs, mesh, shape.global_batch)
         fn = RS.make_train_step(api, accum=2)
-        with mesh_context(mesh), pspec.axes(batch=batch_axes(mesh, 8),
+        with jax.set_mesh(mesh), pspec.axes(batch=batch_axes(mesh, 8),
                                             model_size=4):
             c = jax.jit(fn, in_shardings=(p_sh, o_sh, b_sh),
                         donate_argnums=(0, 1)).lower(params, opt, specs).compile()
         ma = c.memory_analysis()
         print("OK", ma.temp_size_in_bytes >= 0,
-              cost_analysis_dict(c).get("flops", 0) > 0)
+              c.cost_analysis().get("flops", 0) > 0)
     """)
     assert "OK True True" in r.stdout, r.stdout + r.stderr
 

@@ -13,7 +13,7 @@ Three layers of guarantees:
 """
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.timeline import ContentionTimeline, Span, maxmin_fair
 
