@@ -179,9 +179,11 @@ class ContentionTimeline:
 
     def attach_tracer(self, tracer) -> None:
         """Bind a tracer to this clock: span lifecycle events land on the
-        'spans' track group and the tracer's ``vnow`` follows ``now``."""
+        'spans' track group and a virtual tracer's ``vnow`` follows
+        ``now``.  A wall-clock tracer keeps its own clock."""
         self.tracer = tracer
-        tracer.clock = self
+        if not tracer.wall:
+            tracer.clock = self
 
     @staticmethod
     def _track(key) -> Tuple[str, str]:

@@ -379,25 +379,32 @@ def attention_decode_paged(p, cfg, x, k_pages, v_pages, tables, cur_pos, *,
     _, bs, Hkv, D = k_pages.shape
     T = tables.shape[1]
     cur = jnp.asarray(cur_pos, jnp.int32)
-    q, k, v = attention_qkv(p, cfg, x, cur[:, None])
-    if k_scales is not None:
-        kg = (k_pages[tables].astype(jnp.float32)
-              * k_scales[tables][:, :, None, :, None]).astype(k.dtype)
-        vg = (v_pages[tables].astype(jnp.float32)
-              * v_scales[tables][:, :, None, :, None]).astype(v.dtype)
-    else:
-        kg, vg = k_pages[tables], v_pages[tables]
-    kd = _cache_write(kg.reshape(B, T * bs, Hkv, D), k, cur)
-    vd = _cache_write(vg.reshape(B, T * bs, Hkv, D), v, cur)
-    head_keep = None
-    if sparse_threshold:
-        from repro.kernels.paged_attention.ref import block_keep_mask
-        keep = block_keep_mask(q[:, 0], k_pages, tables, cur,
-                               threshold=sparse_threshold, window=window,
-                               k_scales=k_scales)
-        head_keep = jnp.repeat(keep, bs, axis=-1)     # (B, Hkv, T*bs)
-    o = decode_attention(q, kd, vd, cur, window=window, head_keep=head_keep)
-    return o.reshape(B, 1, -1) @ p["wo"], (k, v)
+    # the named scopes let a device trace's per-operation times be read
+    # back by part (they change HLO metadata only, not the program)
+    with jax.named_scope("attn_proj"):
+        q, k, v = attention_qkv(p, cfg, x, cur[:, None])
+    with jax.named_scope("kv_gather"):
+        if k_scales is not None:
+            kg = (k_pages[tables].astype(jnp.float32)
+                  * k_scales[tables][:, :, None, :, None]).astype(k.dtype)
+            vg = (v_pages[tables].astype(jnp.float32)
+                  * v_scales[tables][:, :, None, :, None]).astype(v.dtype)
+        else:
+            kg, vg = k_pages[tables], v_pages[tables]
+        kd = _cache_write(kg.reshape(B, T * bs, Hkv, D), k, cur)
+        vd = _cache_write(vg.reshape(B, T * bs, Hkv, D), v, cur)
+    with jax.named_scope("attention"):
+        head_keep = None
+        if sparse_threshold:
+            from repro.kernels.paged_attention.ref import block_keep_mask
+            keep = block_keep_mask(q[:, 0], k_pages, tables, cur,
+                                   threshold=sparse_threshold, window=window,
+                                   k_scales=k_scales)
+            head_keep = jnp.repeat(keep, bs, axis=-1)     # (B, Hkv, T*bs)
+        o = decode_attention(q, kd, vd, cur, window=window,
+                             head_keep=head_keep)
+    with jax.named_scope("attn_proj"):
+        return o.reshape(B, 1, -1) @ p["wo"], (k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -415,8 +422,9 @@ def init_mlp(key, d_model, d_ff, act, dtype):
 
 
 def mlp_block(p, x, act: str):
-    if act == "silu":
-        h = jax.nn.silu(x @ p["w1"]) * (x @ p["w3"])
-    else:
-        h = jax.nn.gelu(x @ p["w1"])
-    return h @ p["w2"]
+    with jax.named_scope("mlp"):
+        if act == "silu":
+            h = jax.nn.silu(x @ p["w1"]) * (x @ p["w3"])
+        else:
+            h = jax.nn.gelu(x @ p["w1"])
+        return h @ p["w2"]

@@ -374,41 +374,49 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
     def body(carry, xs):
         x, kp_all, vp_all, ks_all, vs_all = carry
         bp, win, li, st, cv = xs
-        h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
+        with jax.named_scope("attn_proj"):
+            h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
         delta = 0.0
         new_st, new_cv = st, cv
         if has_attn(cfg):
-            kp = lax.dynamic_index_in_dim(kp_all, li, 0, keepdims=False)
-            vp = lax.dynamic_index_in_dim(vp_all, li, 0, keepdims=False)
-            ks = vs = None
-            if quant:
-                ks = lax.dynamic_index_in_dim(ks_all, li, 0, keepdims=False)
-                vs = lax.dynamic_index_in_dim(vs_all, li, 0, keepdims=False)
+            with jax.named_scope("kv_gather"):   # this layer's pool
+                kp = lax.dynamic_index_in_dim(kp_all, li, 0, keepdims=False)
+                vp = lax.dynamic_index_in_dim(vp_all, li, 0, keepdims=False)
+                ks = vs = None
+                if quant:
+                    ks = lax.dynamic_index_in_dim(ks_all, li, 0,
+                                                  keepdims=False)
+                    vs = lax.dynamic_index_in_dim(vs_all, li, 0,
+                                                  keepdims=False)
             a_out, (k_new, v_new) = L.attention_decode_paged(
                 bp["attn"], cfg, h, kp, vp, tables, pos, window=win,
                 k_scales=ks, v_scales=vs, sparse_threshold=sparse_threshold)
             # persist only each slot's new row into its current block (a
             # per-slot scatter; the pool slab never round-trips per layer)
-            bs = kp_all.shape[2]
-            blk = jnp.take_along_axis(
-                tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1)[:, None],
-                axis=1)[:, 0]
-            if quant:
-                # read-modify-write requant of each slot's current block
-                row = jnp.arange(B)
-                kf = dequantize_kv(kp[blk], ks[blk])        # (B, bs, Hkv, D)
-                vf = dequantize_kv(vp[blk], vs[blk])
-                kf = kf.at[row, pos % bs].set(k_new[:, 0].astype(kf.dtype))
-                vf = vf.at[row, pos % bs].set(v_new[:, 0].astype(vf.dtype))
-                kq, ksb = quantize_kv(kf, kv_name)
-                vq, vsb = quantize_kv(vf, kv_name)
-                kp_all = kp_all.at[li, blk].set(kq)
-                vp_all = vp_all.at[li, blk].set(vq)
-                ks_all = ks_all.at[li, blk].set(ksb)
-                vs_all = vs_all.at[li, blk].set(vsb)
-            else:
-                kp_all = kp_all.at[li, blk, pos % bs].set(k_new[:, 0])
-                vp_all = vp_all.at[li, blk, pos % bs].set(v_new[:, 0])
+            with jax.named_scope("kv_append"):
+                bs = kp_all.shape[2]
+                blk = jnp.take_along_axis(
+                    tables,
+                    jnp.clip(pos // bs, 0, tables.shape[1] - 1)[:, None],
+                    axis=1)[:, 0]
+                if quant:
+                    # read-modify-write requant of each slot's current block
+                    row = jnp.arange(B)
+                    kf = dequantize_kv(kp[blk], ks[blk])  # (B, bs, Hkv, D)
+                    vf = dequantize_kv(vp[blk], vs[blk])
+                    kf = kf.at[row, pos % bs].set(
+                        k_new[:, 0].astype(kf.dtype))
+                    vf = vf.at[row, pos % bs].set(
+                        v_new[:, 0].astype(vf.dtype))
+                    kq, ksb = quantize_kv(kf, kv_name)
+                    vq, vsb = quantize_kv(vf, kv_name)
+                    kp_all = kp_all.at[li, blk].set(kq)
+                    vp_all = vp_all.at[li, blk].set(vq)
+                    ks_all = ks_all.at[li, blk].set(ksb)
+                    vs_all = vs_all.at[li, blk].set(vsb)
+                else:
+                    kp_all = kp_all.at[li, blk, pos % bs].set(k_new[:, 0])
+                    vp_all = vp_all.at[li, blk, pos % bs].set(v_new[:, 0])
             delta = delta + a_out
         if has_ssm(cfg):
             s_out, sc = SSM.ssm_decode(bp["ssm"], cfg, h,
@@ -424,7 +432,8 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
             m_out, _ = MOE.moe_block(bp["moe"], cfg, h)
             x = x + m_out
         elif "mlp" in bp:
-            h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
+            with jax.named_scope("mlp"):
+                h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
             x = x + L.mlp_block(bp["mlp"], h, cfg.act)
         return (x, kp_all, vp_all, ks_all, vs_all), (new_st, new_cv)
 
@@ -442,9 +451,10 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
     (x, nkp, nvp, nks, nvs), (nst, ncv) = lax.scan(
         body, (x, kp, vp, ks, vs), (params["blocks"], windows, lidx, st, cv))
 
-    x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = (x @ head).astype(jnp.float32)
+    with jax.named_scope("lm_head"):
+        x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
+        head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        logits = (x @ head).astype(jnp.float32)
 
     new_pcache = dict(pcache)
     if has_attn(cfg):

@@ -5,23 +5,28 @@ Every stage transition a request goes through is appended as
 ``dispatch`` when the cluster controller assigns it, ``prefill`` when an
 engine seats it, ``first_token`` at the first stamped token,
 ``handoff_export`` / ``handoff_import`` around a PD migration,
-``requeue`` on failover, ``retire`` at completion.  All timestamps are
-virtual seconds, so the log is deterministic and queryable after a run
-(``timeline(rid)``), and ``summary()`` condenses it into the CLI exit
-line (stage counts + mean admit→first-token / admit→retire hops).
+``requeue`` on failover, ``retire`` at completion.  Timestamps are the
+virtual seconds the producer passes, so the log is deterministic and
+queryable after a run (``timeline(rid)``), and ``summary()`` condenses it
+into the CLI exit line (stage counts + mean admit→first-token /
+admit→retire hops).  A log given a ``clock`` (a wall-clock tracer's)
+stamps each stage with ``clock.now`` when the program records it instead.
 """
 from __future__ import annotations
 
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
 
 class LifecycleLog:
-    """Ordered per-request stage records on the virtual clock."""
+    """Ordered per-request stage records."""
 
-    def __init__(self):
+    def __init__(self, clock: Optional[object] = None):
         self.records: Dict[int, List[Tuple[str, float, dict]]] = {}
+        self.clock = clock
 
     def event(self, rid: int, stage: str, t: float, **info) -> None:
+        if self.clock is not None:
+            t = self.clock.now
         self.records.setdefault(rid, []).append((stage, float(t), info))
 
     def timeline(self, rid: int) -> Tuple[Tuple[str, float, dict], ...]:

@@ -44,12 +44,13 @@ import math
 import time
 from dataclasses import dataclass, field
 from functools import partial as _partial
-from typing import List, Optional
+from typing import List, Optional, Tuple
 
 import numpy as np
 
 from repro.configs.base import ModelConfig
 from repro.core import hw
+from repro.obs.tracer import NO_SPAN, span
 # PhaseCost + the analytic pricing functions moved to repro.profiling;
 # re-exported here because the rest of the stack (and downstream users)
 # import them from repro.serving.engine.
@@ -200,13 +201,26 @@ class EngineBase:
         self._prefix = (getattr(cfg, "n_meta_tokens", 0) or 0) + \
                        (getattr(cfg, "n_img_tokens", 0) or 0)
         # opt-in observability (repro.obs): request lifecycle hops at
-        # seat/first-token/retire/handoff.  Every emission site is guarded
-        # by ``if self.tracer is not None`` so the off path (the default)
-        # executes no tracing code on the hot issue/commit path — pinned
-        # by the zero-allocation guard in tests/test_obs.py.  In cluster
-        # mode worker engines keep tracer=None; the controller records
-        # the same transitions from the protocol messages instead.
+        # seat/first-token/retire/handoff, and on a wall-clock tracer host
+        # spans around each phase and its parts (``obs.tracer.span``).  Every
+        # emission site is guarded by ``if self.tracer is not None`` so the
+        # off path (the default) executes no tracing code on the hot
+        # issue/commit path — pinned by the zero-allocation guard in
+        # tests/test_obs.py.  In cluster mode worker engines keep
+        # tracer=None; the controller records the same transitions from
+        # the protocol messages instead.
         self.tracer = None
+
+    def kv_blocks_read(self) -> Tuple[int, int]:
+        """(addressed, live) KV blocks of the next decode step: the paged
+        decode gathers every slot's whole block table, inactive rows too
+        (``slots * table_width``), while live context fills
+        ``ceil((len + 1) / block_size)`` blocks of each active slot."""
+        bs = self.block_size
+        live = sum(-(-(n + 1) // bs)
+                   for r, n in zip(self.active, self.slot_lens)
+                   if r is not None)
+        return self.slots * self.table_width, live
 
     def metrics_snapshot(self):
         """Flat ((name, value), ...) metrics view — computed on demand
@@ -437,69 +451,82 @@ class EngineBase:
     # -- phase execution: issue (eager) / commit (clock-timed) ---------------
     def issue_prefill(self) -> PendingOp:
         assert self.wants_prefill, "issue_prefill() on a busy/idle engine"
-        # validate the whole candidate wave BEFORE allocating anything, so
-        # a contract violation cannot leak earlier members' blocks
-        for req in self.backlog[:self.slots]:
-            if self._ctx_budget(req) > self.max_len:
-                raise ValueError(
-                    f"request {req.rid} needs {self._ctx_budget(req)} cache "
-                    f"positions > per-slot budget max_len={self.max_len}")
-        wave: List[Request] = []
-        for req in self.backlog[:self.slots]:
-            if not self.pool.can_fit(self._ctx_budget(req)):
-                break  # pool exhausted: the rest stays queued (FIFO)
-            wave.append(req)
-            self._seat_blocks(len(wave) - 1, req, self._alloc_blocks(req))
-            self._register_prefix(len(wave) - 1, req)  # intra-wave sharing
-        if not wave:
-            raise PoolExhausted(
-                f"request {self.backlog[0].rid} needs "
-                f"{self.pool.blocks_for(self._ctx_budget(self.backlog[0]))} "
-                f"blocks; pool has {self.pool.n_free} of {self.pool.n_blocks}")
-        self.backlog = self.backlog[len(wave):]
-        lens = [r.prompt_len for r in wave]
-        cost = self.cost_model.prefill_ragged(
-            lens, [r.cached_len for r in wave] if self.prefix_cache else None)
-        first = self._run_timed("prefill", len(wave), max(lens),
-                                lambda: self._run_prefill(wave))
-        for i, req in enumerate(wave):
-            self.active[i] = req
-            self.slot_lens[i] = self._prefix + req.prompt_len
-            self.assign_order.append(req.rid)
-            if first is not None:  # prefill emits the first token
-                req.tokens.append(int(first[i]))
-                self.slot_tokens[i].append(int(first[i]))
-        for i in range(len(wave), self.slots):
-            self.active[i] = None
-            self.slot_lens[i] = 0
-        self.n_prefills += 1
-        if self.tracer is not None:
-            t = self.tracer.vnow
-            for req in wave:
-                self.tracer.lifecycle.event(req.rid, "prefill", t,
-                                            pid=self.pid,
-                                            cached_len=req.cached_len)
-        return PendingOp("prefill", cost,
-                         list(wave) if first is not None else [])
+        with span(self.tracer, "engine.prefill", self.pid):
+            # validate the whole candidate wave BEFORE allocating anything, so
+            # a contract violation cannot leak earlier members' blocks
+            for req in self.backlog[:self.slots]:
+                if self._ctx_budget(req) > self.max_len:
+                    raise ValueError(
+                        f"request {req.rid} needs {self._ctx_budget(req)} "
+                        f"cache positions > per-slot budget "
+                        f"max_len={self.max_len}")
+            wave: List[Request] = []
+            for req in self.backlog[:self.slots]:
+                if not self.pool.can_fit(self._ctx_budget(req)):
+                    break  # pool exhausted: the rest stays queued (FIFO)
+                wave.append(req)
+                self._seat_blocks(len(wave) - 1, req, self._alloc_blocks(req))
+                # intra-wave sharing
+                self._register_prefix(len(wave) - 1, req)
+            if not wave:
+                need = self.pool.blocks_for(self._ctx_budget(self.backlog[0]))
+                raise PoolExhausted(
+                    f"request {self.backlog[0].rid} needs {need} blocks; "
+                    f"pool has {self.pool.n_free} of {self.pool.n_blocks}")
+            self.backlog = self.backlog[len(wave):]
+            lens = [r.prompt_len for r in wave]
+            cost = self.cost_model.prefill_ragged(
+                lens,
+                [r.cached_len for r in wave] if self.prefix_cache else None)
+            if self.tracer is not None:
+                t = self.tracer.vnow
+                for req in wave:
+                    self.tracer.lifecycle.event(req.rid, "prefill", t,
+                                                pid=self.pid,
+                                                cached_len=req.cached_len)
+            first = self._run_timed("prefill", len(wave), max(lens),
+                                    lambda: self._run_prefill(wave))
+            for i, req in enumerate(wave):
+                self.active[i] = req
+                self.slot_lens[i] = self._prefix + req.prompt_len
+                self.assign_order.append(req.rid)
+                if first is not None:  # prefill emits the first token
+                    req.tokens.append(int(first[i]))
+                    self.slot_tokens[i].append(int(first[i]))
+            for i in range(len(wave), self.slots):
+                self.active[i] = None
+                self.slot_lens[i] = 0
+            self.n_prefills += 1
+            return PendingOp("prefill", cost,
+                             list(wave) if first is not None else [])
 
     def issue_decode(self) -> PendingOp:
         assert self.busy, "issue_decode() on an engine with no active slots"
-        ctxs = [max(l, 1) for r, l in zip(self.active, self.slot_lens)
-                if r is not None]
-        cost = self.cost_model.decode(ctxs)
-        toks = self._run_timed("decode", len(ctxs), sum(ctxs),
-                               self._run_decode)
-        firsts: List[Request] = []
-        for i, req in enumerate(self.active):
-            if req is None:
-                continue
-            self.slot_lens[i] += 1
-            req.tokens.append(int(toks[i]))
-            self.slot_tokens[i].append(int(toks[i]))
-            if req.t_first_token is None:
-                firsts.append(req)
-        self.n_decode_steps += 1
-        return PendingOp("decode", cost, firsts)
+        step = NO_SPAN
+        if self.tracer is not None and self.tracer.wall:
+            addressed, live = self.kv_blocks_read()
+            step = self.tracer.span("engine.decode", tid=self.pid,
+                                    kv_blocks_addressed=addressed,
+                                    kv_blocks_live=live)
+        with step:
+            with span(self.tracer, "engine.decode.price", self.pid):
+                ctxs = [max(l, 1) for r, l in zip(self.active, self.slot_lens)
+                        if r is not None]
+                cost = self.cost_model.decode(ctxs)
+            toks = self._run_timed("decode", len(ctxs), sum(ctxs),
+                                   self._run_decode)
+            with span(self.tracer, "engine.decode.bookkeeping", self.pid):
+                firsts: List[Request] = []
+                for i, req in enumerate(self.active):
+                    if req is None:
+                        continue
+                    self.slot_lens[i] += 1
+                    req.tokens.append(int(toks[i]))
+                    self.slot_tokens[i].append(int(toks[i]))
+                    if req.t_first_token is None:
+                        firsts.append(req)
+                self.n_decode_steps += 1
+            return PendingOp("decode", cost, firsts)
 
     def commit_op(self, pending: PendingOp,
                   t_end: float) -> Optional[PhaseCost]:
@@ -507,13 +534,14 @@ class EngineBase:
         first tokens, retire completed requests, refill freed slots.
         Returns the combined cost of any refill prefills (the caller bills
         them into its tick or schedules them as a follow-on span)."""
-        for req in pending.stamp_first:
-            if req.t_first_token is None:
-                req.t_first_token = t_end
-                if self.tracer is not None:
-                    self.tracer.lifecycle.event(req.rid, "first_token",
-                                                t_end, pid=self.pid)
-        return self._finish_done(t_end)
+        with span(self.tracer, "engine.commit", self.pid):
+            for req in pending.stamp_first:
+                if req.t_first_token is None:
+                    req.t_first_token = t_end
+                    if self.tracer is not None:
+                        self.tracer.lifecycle.event(req.rid, "first_token",
+                                                    t_end, pid=self.pid)
+            return self._finish_done(t_end)
 
     # -- one-shot wrappers (lockstep clock + direct use in tests) ------------
     def prefill_wave(self, now: float) -> PhaseCost:
@@ -557,30 +585,33 @@ class EngineBase:
                     # exhausted now (retried on the next completion);
                     # over-budget requests surface as ValueError at the wave
                     break
-                self.backlog.pop(0)
-                self._seat_blocks(i, nxt, self._alloc_blocks(nxt))
-                self._register_prefix(i, nxt)
-                c = self.cost_model.prefill(1, nxt.prompt_len,
-                                            nxt.cached_len)
-                tok = self._run_timed("prefill", 1, nxt.prompt_len,
-                                      lambda: self._refill_slot(i, nxt))
-                self.active[i] = nxt
-                self.slot_lens[i] = self._prefix + nxt.prompt_len
-                self.assign_order.append(nxt.rid)
-                self.n_refills += 1
-                if self.tracer is not None:
-                    self.tracer.lifecycle.event(nxt.rid, "prefill", t_cursor,
-                                                pid=self.pid, refill=True,
-                                                cached_len=nxt.cached_len)
-                t_cursor += c.duration  # refills in a tick run sequentially
-                extra = c if extra is None else extra.merge(c)
-                if tok is not None:
-                    nxt.tokens.append(int(tok))
-                    self.slot_tokens[i].append(int(tok))
-                    nxt.t_first_token = t_cursor
+                with span(self.tracer, "engine.refill", self.pid):
+                    self.backlog.pop(0)
+                    with span(self.tracer, "engine.refill.seat", self.pid):
+                        self._seat_blocks(i, nxt, self._alloc_blocks(nxt))
+                        self._register_prefix(i, nxt)
+                        c = self.cost_model.prefill(1, nxt.prompt_len,
+                                                    nxt.cached_len)
                     if self.tracer is not None:
-                        self.tracer.lifecycle.event(nxt.rid, "first_token",
-                                                    t_cursor, pid=self.pid)
+                        self.tracer.lifecycle.event(
+                            nxt.rid, "prefill", t_cursor, pid=self.pid,
+                            refill=True, cached_len=nxt.cached_len)
+                    tok = self._run_timed("prefill", 1, nxt.prompt_len,
+                                          lambda: self._refill_slot(i, nxt))
+                    self.active[i] = nxt
+                    self.slot_lens[i] = self._prefix + nxt.prompt_len
+                    self.assign_order.append(nxt.rid)
+                    self.n_refills += 1
+                    t_cursor += c.duration  # a tick's refills run in turn
+                    extra = c if extra is None else extra.merge(c)
+                    if tok is not None:
+                        nxt.tokens.append(int(tok))
+                        self.slot_tokens[i].append(int(tok))
+                        nxt.t_first_token = t_cursor
+                        if self.tracer is not None:
+                            self.tracer.lifecycle.event(
+                                nxt.rid, "first_token", t_cursor,
+                                pid=self.pid)
                 if not nxt.done:
                     break
                 self._retire(i, nxt, t_cursor)
@@ -853,37 +884,46 @@ class PartitionEngine(EngineBase):
         # _run_prefill returns, so mirror the assignment here first)
         for i, req in enumerate(wave):
             self.slot_lens[i] = self._prefix + req.prompt_len
-        logits, cache = self._wave_prefill_cache(wave)
-        if self.paged:
-            self._install_paged(cache, list(range(len(wave))))
-            self.cache = None
-        else:
-            self.cache = cache
-        first = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B,)
-        last = np.ones((self.slots, 1), np.int32)
-        last[:first.shape[0], 0] = np.asarray(first).reshape(-1)[:self.slots]
-        self._last_tok = jnp.asarray(last)
-        return np.asarray(first).reshape(-1)[:len(wave)]
+        with span(self.tracer, "engine.prefill.launch", self.pid):
+            logits, cache = self._wave_prefill_cache(wave)
+        with span(self.tracer, "engine.prefill.write_pages", self.pid):
+            if self.paged:
+                self._install_paged(cache, list(range(len(wave))))
+                self.cache = None
+            else:
+                self.cache = cache
+        with span(self.tracer, "engine.prefill.first_tokens", self.pid):
+            first = jnp.argmax(logits, axis=-1).astype(jnp.int32)  # (B,)
+            last = np.ones((self.slots, 1), np.int32)
+            last[:first.shape[0], 0] = \
+                np.asarray(first).reshape(-1)[:self.slots]
+            self._last_tok = jnp.asarray(last)
+            return np.asarray(first).reshape(-1)[:len(wave)]
 
     def _refill_slot(self, i: int, req: Request):
         import jax.numpy as jnp
 
         prompt = np.asarray(req.prompt, np.int32)
-        lg, c1 = self._prefill_uniform_fn(
-            self.params, self._make_batch([prompt]),
-            self.max_len if not self.paged else self._prefix + req.prompt_len)
+        with span(self.tracer, "engine.refill.prefill", self.pid):
+            lg, c1 = self._prefill_uniform_fn(
+                self.params, self._make_batch([prompt]),
+                self.max_len if not self.paged
+                else self._prefix + req.prompt_len)
         self.slot_lens[i] = self._prefix + req.prompt_len
-        if self.paged:
-            self._install_paged(c1, [i], src_rows=[0])
-        else:
-            for key in ("k", "v", "ssm_state", "ssm_conv"):
-                if key in self.cache:
-                    self.cache[key] = self.cache[key].at[:, i].set(c1[key][:, 0])
-            self.cache["len"] = self.cache["len"].at[i].set(c1["len"][0])
-        tok = int(np.asarray(jnp.argmax(lg, axis=-1)).reshape(-1)[0])
-        last = np.asarray(self._last_tok).copy()
-        last[i, 0] = tok
-        self._last_tok = jnp.asarray(last)
+        with span(self.tracer, "engine.refill.write_pages", self.pid):
+            if self.paged:
+                self._install_paged(c1, [i], src_rows=[0])
+            else:
+                for key in ("k", "v", "ssm_state", "ssm_conv"):
+                    if key in self.cache:
+                        self.cache[key] = self.cache[key].at[:, i].set(
+                            c1[key][:, 0])
+                self.cache["len"] = self.cache["len"].at[i].set(c1["len"][0])
+        with span(self.tracer, "engine.refill.first_token", self.pid):
+            tok = int(np.asarray(jnp.argmax(lg, axis=-1)).reshape(-1)[0])
+            last = np.asarray(self._last_tok).copy()
+            last[i, 0] = tok
+            self._last_tok = jnp.asarray(last)
         return tok
 
     # -- KV handoff device-state movers --------------------------------------
@@ -989,31 +1029,36 @@ class PartitionEngine(EngineBase):
     def _run_decode(self):
         import jax.numpy as jnp
 
-        if self.cfg.family == "encdec":
-            logits, self.cache = self._decode_fn(self.params, self._last_tok,
-                                                 self.cache)
-        elif self.paged:
-            tables = np.zeros((self.slots, self.table_width), np.int32)
-            for i, tbl in enumerate(self.slot_tables):
-                if self.active[i] is not None:
-                    tables[i, :len(tbl)] = tbl
-            pcache = dict(self.pages)
-            pcache["tables"] = jnp.asarray(tables)
-            pcache["lens"] = jnp.asarray(self._device_lens())
-            logits, pcache = self._decode_fn(self.params, self._last_tok,
-                                             pcache)
-            self.pages = {k: v for k, v in pcache.items()
-                          if k not in ("tables", "lens")}
-        else:
-            cache = dict(self.cache)
-            cache["len"] = jnp.asarray(self._device_lens())
-            logits, self.cache = self._decode_fn(self.params, self._last_tok,
-                                                 cache)
-        self._last_tok = jnp.argmax(logits, axis=-1).astype(
-            jnp.int32).reshape(self.slots, 1)
-        self.last_logits = np.asarray(logits, np.float32).reshape(
-            self.slots, -1)
-        return np.asarray(self._last_tok)[:, 0]
+        if self.paged:
+            with span(self.tracer, "engine.decode.tables", self.pid):
+                tables = np.zeros((self.slots, self.table_width), np.int32)
+                for i, tbl in enumerate(self.slot_tables):
+                    if self.active[i] is not None:
+                        tables[i, :len(tbl)] = tbl
+                pcache = dict(self.pages)
+                pcache["tables"] = jnp.asarray(tables)
+                pcache["lens"] = jnp.asarray(self._device_lens())
+        with span(self.tracer, "engine.decode.launch", self.pid):
+            if self.cfg.family == "encdec":
+                logits, self.cache = self._decode_fn(
+                    self.params, self._last_tok, self.cache)
+            elif self.paged:
+                logits, pcache = self._decode_fn(self.params, self._last_tok,
+                                                 pcache)
+                self.pages = {k: v for k, v in pcache.items()
+                              if k not in ("tables", "lens")}
+            else:
+                cache = dict(self.cache)
+                cache["len"] = jnp.asarray(self._device_lens())
+                logits, self.cache = self._decode_fn(
+                    self.params, self._last_tok, cache)
+            self._last_tok = jnp.argmax(logits, axis=-1).astype(
+                jnp.int32).reshape(self.slots, 1)
+        with span(self.tracer, "engine.decode.logits_to_host", self.pid):
+            self.last_logits = np.asarray(logits, np.float32).reshape(
+                self.slots, -1)
+        with span(self.tracer, "engine.decode.tokens_to_host", self.pid):
+            return np.asarray(self._last_tok)[:, 0]
 
     def _supports_slot_refill(self) -> bool:
         return self.cfg.family != "encdec" and not self.wave_only

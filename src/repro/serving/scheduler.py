@@ -61,6 +61,7 @@ import numpy as np
 
 from repro.core import hw
 from repro.core.timeline import ContentionTimeline, Span, maxmin_fair
+from repro.obs.tracer import span
 from repro.serving.engine import PendingOp
 from repro.serving.metrics import ServingMetrics, achieved_bw_stats
 from repro.serving.queue import RequestQueue
@@ -284,8 +285,10 @@ class EventScheduler:
         """Wire one tracer through the whole in-process stack: the
         timeline (span begin/end + the bw counter track), the queue
         (admission instants), every engine (request lifecycles), and this
-        scheduler's policy decisions.  The tracer's clock becomes the
-        shared contention timeline."""
+        scheduler's policy decisions.  A virtual tracer's clock becomes
+        the shared contention timeline; a wall-clock tracer keeps its own
+        and also times each event this scheduler handles
+        (``scheduler.step``) and the engines' host work."""
         self.tracer = tracer
         self.timeline.attach_tracer(tracer)
         self.queue.tracer = tracer
@@ -331,27 +334,30 @@ class EventScheduler:
         self._inflight[id(e)] = sp
 
     def _complete(self, e, pend: PendingOp, sp: Span, t: float) -> None:
-        del self._inflight[id(e)]
-        if pend.kind == "prefill":
-            self._prefill_live -= 1
-        extra = e.commit_op(pend, t)
-        self._record(sp.t_start, t, e.pid, pend.kind, pend.cost.demand)
-        self._harvest()
-        if extra is not None:
-            # slot-refill prefills run sequentially after the op that freed
-            # the slots, before this partition's next op (as under lockstep)
-            sp2 = self.timeline.start(
-                extra.duration, extra.byts, key=(e.pid, "refill"),
-                on_complete=lambda sp2, t2, e=e, extra=extra:
-                    self._refill_done(e, extra, sp2, t2))
-            self._inflight[id(e)] = sp2
-        self._pump(t)
+        with span(self.tracer, "scheduler.step"):
+            del self._inflight[id(e)]
+            if pend.kind == "prefill":
+                self._prefill_live -= 1
+            extra = e.commit_op(pend, t)
+            self._record(sp.t_start, t, e.pid, pend.kind, pend.cost.demand)
+            self._harvest()
+            if extra is not None:
+                # slot-refill prefills run sequentially after the op that
+                # freed the slots, before this partition's next op (as
+                # under lockstep)
+                sp2 = self.timeline.start(
+                    extra.duration, extra.byts, key=(e.pid, "refill"),
+                    on_complete=lambda sp2, t2, e=e, extra=extra:
+                        self._refill_done(e, extra, sp2, t2))
+                self._inflight[id(e)] = sp2
+            self._pump(t)
 
     def _refill_done(self, e, extra, sp: Span, t: float) -> None:
-        del self._inflight[id(e)]
-        self._record(sp.t_start, t, e.pid, "refill", extra.demand)
-        self._harvest()
-        self._pump(t)
+        with span(self.tracer, "scheduler.step"):
+            del self._inflight[id(e)]
+            self._record(sp.t_start, t, e.pid, "refill", extra.demand)
+            self._harvest()
+            self._pump(t)
 
     def _record(self, t0: float, t1: float, pid: int, phase: str,
                 demand: float) -> None:

@@ -311,3 +311,131 @@ def test_pd_cluster_trace_pairs_handoff_flows():
     counts = tracer.lifecycle.stage_counts()
     assert counts["handoff_export"] == counts["handoff_import"] == \
         ctl.router.n_handoffs
+
+
+# ---------------------------------------------------------------------------
+# clocks: the virtual trace as it was, the wall clock beside it
+# ---------------------------------------------------------------------------
+
+
+def _digest(tracer) -> tuple:
+    import hashlib
+
+    doc = json.dumps(to_chrome(tracer.events), sort_keys=True).encode()
+    lc = json.dumps({str(k): v for k, v in
+                     sorted(tracer.lifecycle.records.items())},
+                    sort_keys=True).encode()
+    return (hashlib.sha256(doc).hexdigest()[:16],
+            hashlib.sha256(lc).hexdigest()[:16])
+
+
+@pytest.mark.parametrize("run, want", [
+    (lambda: _traced_run()[0],
+     ("e457643c064587f8", "8a0780fbec12f24c")),
+    (lambda: _traced_run(policy="none", partitions=1)[0],
+     ("0215bff9031b3605", "8e3d73a3b6e51b42")),
+    (lambda: _traced_cluster("pd", workers=2, n=6)[0],
+     ("45e1ecccc5bedefb", None)),
+])
+def test_virtual_trace_matches_its_pinned_digest(run, want):
+    """Host spans and wall-clock stamps leave a virtual-clock trace byte
+    for byte as it was before them (digests of the export and of the
+    lifecycle records, recorded before the host spans existed)."""
+    got = _digest(run())
+    assert got[0] == want[0]
+    if want[1] is not None:
+        assert got[1] == want[1]
+
+
+def test_wall_tracer_keeps_its_clock_and_stamps_on_emission(tmp_path):
+    import time
+
+    from repro.obs import WallClock
+
+    cfg = _cfg()
+    q = RequestQueue()
+    tracer = Tracer(clock=WallClock())
+    sched = make_scheduler(_fleet(cfg, 2), q, policy="demand",
+                           bandwidth=2e9, clock="event")
+    sched.attach_tracer(tracer)
+    assert isinstance(tracer.clock, WallClock)
+    t0 = time.perf_counter()
+    _load(q, 6)
+    sched.run()
+    t1 = time.perf_counter()
+    ts = [ev["t"] for ev in tracer.events]
+    assert ts == sorted(ts) and t0 <= ts[0] and ts[-1] <= t1
+    names = {ev["name"] for ev in tracer.events if ev["group"] == "host"}
+    assert {"scheduler.step", "engine.decode", "engine.decode.price",
+            "engine.decode.bookkeeping", "engine.prefill",
+            "engine.commit"} <= names
+    for recs in tracer.lifecycle.records.values():
+        stamp = {s: t for s, t, _ in reversed(recs)}
+        assert t0 <= stamp["submit"] <= stamp["prefill"] \
+            <= stamp["first_token"] <= stamp["retire"] <= t1
+    assert validate_chrome(to_chrome(tracer.events)) == []
+
+
+def test_span_is_a_noop_on_the_virtual_clock():
+    from repro.obs.tracer import NO_SPAN
+
+    tracer = Tracer()
+    with tracer.span("engine.decode", kv_blocks_live=3) as sp:
+        pass
+    assert tracer.span("x") is NO_SPAN and sp is None
+    assert tracer.events == [] and tracer.open_spans == []
+
+
+def test_wall_span_nests_records_and_annotates(monkeypatch):
+    from repro.obs import WallClock
+
+    tracer = Tracer(clock=WallClock())
+    entered = []
+
+    class Annotation:
+        def __init__(self, name):
+            self.name = name
+
+        def __enter__(self):
+            entered.append(self.name)
+
+        def __exit__(self, *exc):
+            entered.append("/" + self.name)
+
+    tracer._annotation = Annotation
+    with tracer.span("engine.decode", tid=2, kv_blocks_live=5):
+        assert tracer.open_spans == ["engine.decode"]
+        with tracer.span("engine.decode.launch", tid=2):
+            assert tracer.open_spans[-1] == "engine.decode.launch"
+    assert tracer.open_spans == []
+    assert entered == ["engine.decode", "engine.decode.launch",
+                       "/engine.decode.launch", "/engine.decode"]
+    assert [(e["ph"], e["name"]) for e in tracer.events] == [
+        ("B", "engine.decode"), ("B", "engine.decode.launch"),
+        ("E", "engine.decode.launch"), ("E", "engine.decode")]
+    assert tracer.events[0]["args"] == {"kv_blocks_live": 5}
+    assert all(e["group"] == "host" and e["tid"] == 2
+               for e in tracer.events)
+
+
+def test_kv_block_counts_by_hand():
+    """Addressed blocks are every slot's whole table, inactive rows too;
+    live blocks cover each active slot's context plus the new token."""
+    from repro.obs import WallClock
+    from repro.serving.queue import Request
+
+    eng = SimulatedEngine(_cfg(), slots=4, max_len=64, block_size=16)
+    assert eng.table_width == 4
+    reqs = [Request(rid=k, prompt=np.ones(4, np.int32), max_new_tokens=9)
+            for k in range(3)]
+    for i, (req, n) in enumerate(zip(reqs, [15, 16, 40])):
+        eng.active[i + (i > 0)] = req
+        eng.slot_lens[i + (i > 0)] = n
+    # slots: 0 -> 15 (16 positions, 1 block), 1 idle, 2 -> 16 (17, 2
+    # blocks), 3 -> 40 (41, 3 blocks)
+    assert eng.kv_blocks_read() == (16, 6)
+    eng.tracer = Tracer(clock=WallClock())
+    eng.issue_decode()
+    b = next(e for e in eng.tracer.events
+             if e["ph"] == "B" and e["name"] == "engine.decode")
+    assert b["args"] == {"kv_blocks_addressed": 16, "kv_blocks_live": 6}
