@@ -1,21 +1,23 @@
 """Paged decode-attention Pallas TPU kernel: block-table KV pages.
 
 Same online-softmax streaming structure as ``repro.kernels.flash_decode``,
-but K/V live in a shared pool of fixed-size blocks ``(N, bs, Hkv, D)`` and
-each slot reads its own chain of blocks through a block table.  The table
-(and the per-slot context lengths) ride in as *scalar-prefetch* operands —
-``PrefetchScalarGridSpec`` makes them available to the BlockSpec index maps,
-so grid step ``(b, t)`` DMAs physical block ``tables[b, t]`` straight from
-HBM without ever materializing the dense gather.
+but K/V live in a shared pool of fixed-size head-major blocks
+``(N, Hkv, bs, D)`` and each slot reads its own chain of blocks through a
+block table.  The table (and the per-slot context lengths) ride in as
+*scalar-prefetch* operands — ``PrefetchScalarGridSpec`` makes them
+available to the BlockSpec index maps, so grid step ``(b, t)`` DMAs
+physical block ``tables[b, t]`` straight from HBM without ever
+materializing the dense gather.
 
 Grid: ``(B, T)`` with the block axis sequential per slot.  One grid step
-moves a whole ``(bs, Hkv, D)`` block — every KV head at once — because a
-TPU block's last two dimensions must be tile-aligned or span the array, and
-``(Hkv, D)`` spans it at any head count.  The kernel body loops over the
-heads; q rows pack each head's GQA group so one MXU dot serves every query
-head of that kv head.  Positions past a slot's ``cur_pos`` (including whole
-null-padded blocks of short slots) are masked by absolute logical position,
-so ragged contexts stream the same way as full ones.
+moves a whole ``(Hkv, bs, D)`` block — every KV head at once, each head's
+``(bs, D)`` tile contiguous — because a TPU block's last two dimensions must
+be tile-aligned or span the array, and ``(bs, D)`` spans it at any head
+count.  The kernel body loops over the heads; q rows pack each head's GQA
+group so one MXU dot serves every query head of that kv head.  Positions
+past a slot's ``cur_pos`` (including whole null-padded blocks of short
+slots) are masked by absolute logical position, so ragged contexts stream
+the same way as full ones.
 """
 from __future__ import annotations
 
@@ -78,9 +80,9 @@ def _pa_kernel(tables_ref, cur_ref, q_ref, k_ref, v_ref, o_ref,
         ok = _position_mask(cur_ref[b], t, bs, window)
         for h in range(n_kv):
             q = q_ref[0, h]              # (G, D)
-            k = k_ref[0, :, h, :]        # (bs, D)
+            k = k_ref[0, h]              # (bs, D)
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
-            _online_softmax_step(h, jnp.where(ok, s, _NEG), v_ref[0, :, h, :],
+            _online_softmax_step(h, jnp.where(ok, s, _NEG), v_ref[0, h],
                                  m_ref, l_ref, acc_ref)
 
     _init_and_finish(t, n_t, o_ref, m_ref, l_ref, acc_ref, body)
@@ -106,7 +108,7 @@ def _grid_spec(B, T, Hkv, G, D, page_specs, n_prefetch):
 
 def _prep(q, k_pages, tables, cur_pos):
     B, Hq, D = q.shape
-    _, bs, Hkv, _ = k_pages.shape
+    _, Hkv, bs, _ = k_pages.shape
     if Hq % Hkv:
         raise ValueError(f"{Hq} query heads do not group over {Hkv} kv heads")
     G = Hq // Hkv
@@ -117,13 +119,13 @@ def _prep(q, k_pages, tables, cur_pos):
 
 
 def _page_spec(bs, Hkv, D):
-    return pl.BlockSpec((1, bs, Hkv, D),
+    return pl.BlockSpec((1, Hkv, bs, D),
                         lambda b, t, tbl, *_: (tbl[b, t], 0, 0, 0))
 
 
 def paged_decode_pallas(q, k_pages, v_pages, tables, cur_pos, *,
                         window: int = 0, interpret: bool = False):
-    """q: (B, Hq, D); pages: (N, bs, Hkv, D); tables: (B, T) int32;
+    """q: (B, Hq, D); pages: (N, Hkv, bs, D); tables: (B, T) int32;
     cur_pos: (B,) int32.  Returns (B, Hq, D); 1/sqrt(D) folded into q."""
     qg, tables, cur, (B, Hq, D, bs, Hkv, G, T) = \
         _prep(q, k_pages, tables, cur_pos)
@@ -152,8 +154,8 @@ def _pa_quant_kernel(tables_ref, cur_ref, q_ref, k_ref, v_ref, ks_ref,
         ok = _position_mask(cur_ref[b], t, bs, window)
         for h in range(n_kv):
             q = q_ref[0, h].astype(jnp.float32)                # (G, D)
-            k = k_ref[0, :, h, :].astype(jnp.float32) * ks_ref[0, :, h:h + 1]
-            v = v_ref[0, :, h, :].astype(jnp.float32) * vs_ref[0, :, h:h + 1]
+            k = k_ref[0, h].astype(jnp.float32) * ks_ref[0, :, h:h + 1]
+            v = v_ref[0, h].astype(jnp.float32) * vs_ref[0, :, h:h + 1]
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
             _online_softmax_step(h, jnp.where(ok, s, _NEG), v,
                                  m_ref, l_ref, acc_ref)
@@ -164,7 +166,7 @@ def _pa_quant_kernel(tables_ref, cur_ref, q_ref, k_ref, v_ref, ks_ref,
 def paged_decode_quant_pallas(q, k_pages, v_pages, k_scales, v_scales,
                               tables, cur_pos, *, window: int = 0,
                               interpret: bool = False):
-    """Quantized paged decode: pages (N, bs, Hkv, D) packed int8/fp8,
+    """Quantized paged decode: pages (N, Hkv, bs, D) packed int8/fp8,
     scales (N, Hkv) f32.  Same grid and streaming structure as the dense
     kernel; each block's scales ride along through the same block-table
     index map, so the gather stays one DMA per (slot, block)."""
@@ -203,10 +205,10 @@ def _pa_sparse_kernel(tables_ref, cur_ref, keep_ref, read_ref, q_ref, k_ref,
         ok = _position_mask(cur_ref[b], t, bs, window)
         for h in range(n_kv):
             q = q_ref[0, h]
-            k = k_ref[0, :, h, :]
+            k = k_ref[0, h]
             s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)
             s = jnp.where(ok & (keep_ref[b, h, t] > 0), s, _NEG)
-            _online_softmax_step(h, s, v_ref[0, :, h, :],
+            _online_softmax_step(h, s, v_ref[0, h],
                                  m_ref, l_ref, acc_ref)
 
     _init_and_finish(t, n_t, o_ref, m_ref, l_ref, acc_ref, body)
@@ -228,7 +230,7 @@ def paged_decode_sparse_pallas(q, k_pages, v_pages, tables, cur_pos, keep, *,
     # a block no head keeps reads the null block (id 0): tiny, cache-hot,
     # masked out
     page_spec = pl.BlockSpec(
-        (1, bs, Hkv, D),
+        (1, Hkv, bs, D),
         lambda b, t, tbl, cur, kp, rd: (tbl[b, t] * rd[b, t], 0, 0, 0))
     out = pl.pallas_call(
         kernel,

@@ -46,31 +46,31 @@ def _attend(q, kd, vd, cur_pos, window: int, head_keep=None):
 
 def paged_decode_attention(q, k_pages, v_pages, tables, cur_pos, *,
                            window: int = 0):
-    """q: (B, Hq, D); pages: (N, bs, Hkv, D); tables: (B, T) int32 block ids
-    into the pool; cur_pos: (B,) int32 — logical positions [0, cur_pos[b]]
-    of slot b are valid (block t of slot b covers positions
-    [t*bs, (t+1)*bs)).  Returns (B, Hq, D)."""
-    B = q.shape[0]
-    _, bs, Hkv, D = k_pages.shape
-    S = tables.shape[1] * bs
+    """q: (B, Hq, D); pages: (N, Hkv, bs, D) head-major blocks; tables:
+    (B, T) int32 block ids into the pool; cur_pos: (B,) int32 — logical
+    positions [0, cur_pos[b]] of slot b are valid (block t of slot b covers
+    positions [t*bs, (t+1)*bs)).  Returns (B, Hq, D)."""
     # dense per-slot view via the block table (the gather the kernel avoids)
-    kd = k_pages[tables].reshape(B, S, Hkv, D)
-    vd = v_pages[tables].reshape(B, S, Hkv, D)
-    return _attend(q, kd, vd, cur_pos, window)
+    return _attend(q, _dense(k_pages[tables]), _dense(v_pages[tables]),
+                   cur_pos, window)
+
+
+def _dense(blocks):
+    """(B, T, Hkv, bs, D) gathered blocks -> the (B, T*bs, Hkv, D) sequence
+    they hold."""
+    B, T, Hkv, bs, D = blocks.shape
+    return blocks.swapaxes(2, 3).reshape(B, T * bs, Hkv, D)
 
 
 def _dequant_gather(pages, scales, tables, dtype):
     """(B, T*bs, Hkv, D) float view of packed pages through the table."""
-    B, T = tables.shape
-    _, bs, Hkv, D = pages.shape
-    x = pages[tables].astype(jnp.float32) \
-        * scales[tables][:, :, None, :, None]
-    return x.reshape(B, T * bs, Hkv, D).astype(dtype)
+    x = pages[tables].astype(jnp.float32) * scales[tables][..., None, None]
+    return _dense(x).astype(dtype)
 
 
 def paged_decode_attention_quant(q, k_pages, v_pages, k_scales, v_scales,
                                  tables, cur_pos, *, window: int = 0):
-    """Quantized-layout oracle: pages (N, bs, Hkv, D) int8/fp8 packed,
+    """Quantized-layout oracle: pages (N, Hkv, bs, D) int8/fp8 packed,
     scales (N, Hkv) f32 per (block, kv-head).  Dequantizes the dense
     gather (``x * scale``) and runs the dense ref's math — the kernel does
     the same multiply in VMEM instead."""
@@ -98,15 +98,15 @@ def block_keep_mask(q, k_pages, tables, cur_pos, *, threshold: float,
     ``mean(q * scale) == scale * mean(q)``.
     """
     B, Hq, D = q.shape
-    _, bs, Hkv, _ = k_pages.shape
+    _, Hkv, bs, _ = k_pages.shape
     T = tables.shape[1]
     G = Hq // Hkv
     cur = jnp.asarray(cur_pos, jnp.int32)
     if k_scales is not None:
-        kmean = k_pages.astype(jnp.float32).mean(axis=1) \
+        kmean = k_pages.astype(jnp.float32).mean(axis=2) \
             * k_scales[..., None]                     # (N, Hkv, D)
     else:
-        kmean = k_pages.mean(axis=1)                  # (N, Hkv, D)
+        kmean = k_pages.mean(axis=2)                  # (N, Hkv, D)
     km = kmean[tables]                                # (B, T, Hkv, D)
     qr = q.reshape(B, Hkv, G, D)
     s = jnp.einsum("bhgd,bthd->bhgt", qr, km.astype(qr.dtype),
@@ -131,12 +131,9 @@ def paged_decode_attention_sparse(q, k_pages, v_pages, tables, cur_pos, *,
     out wholesale before the softmax.  Selection comes from
     ``block_keep_mask``; at ``threshold == 0`` this is exactly the dense
     ref (every valid block kept)."""
-    B = q.shape[0]
-    _, bs, Hkv, D = k_pages.shape
-    S = tables.shape[1] * bs
+    bs = k_pages.shape[2]
     keep = block_keep_mask(q, k_pages, tables, cur_pos,
                            threshold=threshold, window=window)
     head_keep = jnp.repeat(keep, bs, axis=-1)         # (B, Hkv, S)
-    kd = k_pages[tables].reshape(B, S, Hkv, D)
-    vd = v_pages[tables].reshape(B, S, Hkv, D)
-    return _attend(q, kd, vd, cur_pos, window, head_keep=head_keep)
+    return _attend(q, _dense(k_pages[tables]), _dense(v_pages[tables]),
+                   cur_pos, window, head_keep=head_keep)

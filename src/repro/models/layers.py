@@ -217,35 +217,49 @@ def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0):
     return o.reshape(B, Sq, Hq, D).astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, cur_pos, *, window=None,
-                     head_keep=None):
+def decode_attention(q, k_cache, v_cache, cur_pos, *, window=None):
     """Single-token attention against a (possibly longer) cache.
 
     q: (B, 1, Hq, D); caches: (B, S, Hkv, D); cur_pos: () or (B,) int32 —
     0-indexed position of each slot's current token (cache entries
     [0, cur_pos[b]] are valid; a vector gives every slot its own context
     length, the masked-attention half of per-slot continuous batching).
-    ``head_keep`` (optional, (B, Hkv, S) bool) masks positions per kv-head
-    on top of the causal/window mask (the blockwise-sparse paged path).
+    The cache is read as S blocks of one position each.
+    """
+    return block_attention(q, k_cache[:, :, :, None], v_cache[:, :, :, None],
+                           cur_pos, window=window)
+
+
+def block_attention(q, k_blocks, v_blocks, cur_pos, *, window=None,
+                    block_keep=None):
+    """Single-token attention over gathered head-major KV blocks, read in
+    the order they are stored, without flattening the blocks into one
+    sequence axis.
+
+    q: (B, 1, Hq, D); blocks: (B, T, Hkv, bs, D), block ``t`` holding
+    positions [t*bs, (t+1)*bs); cur_pos: () or (B,) int32.  The softmax runs
+    over all T*bs positions.  ``block_keep`` (optional, (B, Hkv, T) bool)
+    masks whole blocks per kv-head on top of the causal/window mask (the
+    blockwise-sparse path).
     """
     B, _, Hq, D = q.shape
-    _, S, Hkv, _ = k_cache.shape
+    _, T, Hkv, bs, _ = k_blocks.shape
     G = Hq // Hkv
     qr = q.reshape(B, Hkv, G, D)
-    s = jnp.einsum("bhgd,bkhd->bhgk", qr, k_cache,
+    s = jnp.einsum("bhgd,bthsd->bhgts", qr, k_blocks,
                    preferred_element_type=jnp.float32) / math.sqrt(D)
-    pos = jnp.arange(S, dtype=jnp.int32)
-    cur = jnp.asarray(cur_pos, jnp.int32).reshape(-1, 1)  # (1,1) or (B,1)
-    ok = pos[None, :] <= cur
+    pos = jnp.arange(T * bs, dtype=jnp.int32).reshape(T, bs)
+    cur = jnp.asarray(cur_pos, jnp.int32).reshape(-1, 1, 1)   # (B|1, 1, 1)
+    ok = pos[None] <= cur                                     # (B, T, bs)
     weff = _window_len(window)
     if weff is not None:
-        ok &= pos[None, :] > (cur - weff)
-    mask = ok[:, None, None, :]
-    if head_keep is not None:
-        mask = mask & head_keep[:, :, None, :]
+        ok &= pos[None] > (cur - weff)
+    mask = ok[:, None, None]                                  # (B,1,1,T,bs)
+    if block_keep is not None:
+        mask = mask & block_keep[:, :, None, :, None]
     s = jnp.where(mask, s, _NEG)
-    p = jax.nn.softmax(s, axis=-1)
-    o = jnp.einsum("bhgk,bkhd->bhgd", p.astype(v_cache.dtype), v_cache,
+    p = jax.nn.softmax(s, axis=(-2, -1))
+    o = jnp.einsum("bhgts,bthsd->bhgd", p.astype(v_blocks.dtype), v_blocks,
                    preferred_element_type=jnp.float32)
     return o.reshape(B, 1, Hq, D).astype(q.dtype)
 
@@ -353,58 +367,51 @@ def attention_decode_slice(p, cfg, x, cache, cur_pos, *, window=None):
     return o.reshape(B, 1, -1) @ p["wo"], (k, v)
 
 
-def attention_decode_paged(p, cfg, x, k_pages, v_pages, tables, cur_pos, *,
-                           window=None, k_scales=None, v_scales=None,
+def attention_decode_paged(p, q, k_pages, v_pages, layer, tables, cur_pos,
+                           *, window=None, k_scales=None, v_scales=None,
                            sparse_threshold=0.0):
-    """Decode attention against one layer's paged KV pool.
+    """Decode attention of the new token against layer ``layer`` of the
+    paged pool.
 
-    x: (B, 1, d); pages: (N, bs, Hkv, D); tables: (B, T) int32 block ids
-    (null-padded); cur_pos: (B,) int32 per-slot write position.  Each
-    slot's block chain is gathered to a dense (B, T*bs, ...) view, the new
-    token's K/V row is placed at its logical position in that view, and
-    the same masked attention as the dense path runs over it (the Pallas
-    kernel in ``repro.kernels.paged_attention`` streams blocks instead of
-    gathering).  Returns (out, (k_new, v_new)): the CALLER persists the new
-    row into the pool — block ``tables[b, cur//bs]``, offset ``cur % bs`` —
-    so the layer-stacked pool slab never round-trips through this function
-    (the paged analogue of ``attention_decode_slice``).
+    q: (B, 1, Hq, D) projected queries; pages: (L, N, Hkv, bs, D) head-major
+    blocks that already hold each slot's new row (the caller appends it
+    first, ``transformer.decode_step_paged``); tables: (B, T) int32 block
+    ids (null-padded); cur_pos: (B,) int32 per-slot position.  Each slot's
+    block chain is gathered straight from the layer-stacked pool to
+    (B, T, Hkv, bs, D) and attended in that order (``block_attention``), so
+    neither the pool, nor the layer's slice of it, nor the gathered view is
+    copied into another layout (the Pallas kernel in
+    ``repro.kernels.paged_attention`` streams blocks instead of
+    gathering).  Returns the attention output projected by ``wo``.
 
-    ``k_scales``/``v_scales`` ((N, Hkv) f32) mark a quantized pool layout:
-    packed int8/fp8 pages are dequantized on the gather.  A positive
-    ``sparse_threshold`` (static) drops whole KV blocks whose estimated
-    attention mass falls below it — selection comes from the kernel
-    oracle's ``block_keep_mask`` so model path and kernel agree.
+    ``k_scales``/``v_scales`` ((L, N, Hkv) f32) mark a quantized pool
+    layout: packed int8/fp8 pages are dequantized on the gather.  A
+    positive ``sparse_threshold`` (static) drops whole KV blocks whose
+    estimated attention mass falls below it — selection comes from the
+    kernel oracle's ``block_keep_mask`` so model path and kernel agree.
     """
-    B = x.shape[0]
-    _, bs, Hkv, D = k_pages.shape
-    T = tables.shape[1]
+    B = q.shape[0]
     cur = jnp.asarray(cur_pos, jnp.int32)
     # the named scopes let a device trace's per-operation times be read
     # back by part (they change HLO metadata only, not the program)
-    with jax.named_scope("attn_proj"):
-        q, k, v = attention_qkv(p, cfg, x, cur[:, None])
     with jax.named_scope("kv_gather"):
+        kg, vg = k_pages[layer, tables], v_pages[layer, tables]
         if k_scales is not None:
-            kg = (k_pages[tables].astype(jnp.float32)
-                  * k_scales[tables][:, :, None, :, None]).astype(k.dtype)
-            vg = (v_pages[tables].astype(jnp.float32)
-                  * v_scales[tables][:, :, None, :, None]).astype(v.dtype)
-        else:
-            kg, vg = k_pages[tables], v_pages[tables]
-        kd = _cache_write(kg.reshape(B, T * bs, Hkv, D), k, cur)
-        vd = _cache_write(vg.reshape(B, T * bs, Hkv, D), v, cur)
+            kg = (kg.astype(jnp.float32)
+                  * k_scales[layer, tables][..., None, None]).astype(q.dtype)
+            vg = (vg.astype(jnp.float32)
+                  * v_scales[layer, tables][..., None, None]).astype(q.dtype)
     with jax.named_scope("attention"):
-        head_keep = None
+        keep = None
         if sparse_threshold:
             from repro.kernels.paged_attention.ref import block_keep_mask
-            keep = block_keep_mask(q[:, 0], k_pages, tables, cur,
-                                   threshold=sparse_threshold, window=window,
-                                   k_scales=k_scales)
-            head_keep = jnp.repeat(keep, bs, axis=-1)     # (B, Hkv, T*bs)
-        o = decode_attention(q, kd, vd, cur, window=window,
-                             head_keep=head_keep)
+            keep = block_keep_mask(
+                q[:, 0], k_pages[layer], tables, cur,
+                threshold=sparse_threshold, window=window,
+                k_scales=None if k_scales is None else k_scales[layer])
+        o = block_attention(q, kg, vg, cur, window=window, block_keep=keep)
     with jax.named_scope("attn_proj"):
-        return o.reshape(B, 1, -1) @ p["wo"], (k, v)
+        return o.reshape(B, 1, -1) @ p["wo"]
 
 
 # ---------------------------------------------------------------------------

@@ -338,38 +338,64 @@ def decode_step(params, cfg, token, cache):
     return logits, new_cache
 
 
+def _append_block(pages, scales, li, blk, at_row, new, kv_name):
+    """Put each slot's new row into its current block and write the whole
+    block back: ``pages`` (L, N, Hkv, bs, D) at layer ``li``, ``blk`` (B,)
+    block ids, ``at_row`` (B, 1, bs, 1) marking each slot's row, ``new``
+    (B, 1, Hkv, D).  A quantized pool (``scales`` (L, N, Hkv) given)
+    requantizes the block with a fresh scale.  Returns (pages, scales)."""
+    cur = pages[li, blk]                                  # (B, Hkv, bs, D)
+    row = new[:, 0, :, None, :]                           # (B, Hkv, 1, D)
+    if scales is None:
+        return pages.at[li, blk].set(
+            jnp.where(at_row, row.astype(pages.dtype), cur)), None
+    # lazy: serving imports models, so models must not import serving at
+    # module scope
+    from repro.serving.kv_pool import dequantize_kv, quantize_kv
+    full = dequantize_kv(cur, scales[li, blk])
+    packed, s = quantize_kv(
+        jnp.where(at_row, row.astype(full.dtype), full), kv_name)
+    return pages.at[li, blk].set(packed), scales.at[li, blk].set(s)
+
+
 def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
     """One decode step against a paged (block-table) KV pool.
 
     token: (B, 1) int32.  pcache:
-      k_pages/v_pages : (L, N, bs, Hkv, D) shared block pool
+      k_pages/v_pages : (L, N, Hkv, bs, D) shared pool of head-major blocks
       tables          : (B, T) int32 per-slot block chains (null-padded)
       lens            : (B,) int32 per-slot write positions
       k_scales/v_scales (quantized pools): (L, N, Hkv) f32 per-(block,
         kv-head) scales; their presence marks packed int8/fp8 pages
       ssm_state/ssm_conv (families with SSM): per-slot as in the dense cache
     Same math as ``decode_step`` on the dense gather of each slot's chain —
-    the equivalence the engine test suite pins down.  On a quantized pool
-    the append is a per-layer read-modify-write: each slot's current block
-    is dequantized, the new row set, and the whole (bs, D) tile requantized
-    with a fresh scale (per-step re-rounding error stays bounded by
-    ``scale / 2`` per element; see docs/kv_quantization.md).  A positive
+    the equivalence the engine test suite pins down.  Each layer first
+    appends: it reads each slot's current block, puts the new row in, and
+    writes the whole block back (``_append_block``); attention then reads
+    the slot's chain, new row included, in the order the pool stores it.
+    On a quantized pool the append requantizes the whole block with a
+    fresh scale per (bs, D) tile (per-step re-rounding error stays bounded
+    by ``scale / 2`` per element; see docs/kv_quantization.md).  A positive
     ``sparse_threshold`` (static) makes attention skip low-mass KV blocks.
     Returns (logits (B, 1, V) f32, new pcache) with every ``lens`` advanced
     by one (the engine overrides lengths for inactive slots from host
     bookkeeping).
     """
     x = pbatch(params["embed"][token])  # (B,1,d)
-    B = x.shape[0]
     pos = jnp.asarray(pcache["lens"], jnp.int32)
     tables = jnp.asarray(pcache["tables"], jnp.int32)
     windows = jnp.asarray(layer_windows(cfg))
     quant = "k_scales" in pcache
+    kv_name = None
     if quant:
-        # lazy: serving imports models, so models must not import serving
-        # at module scope
-        from repro.serving.kv_pool import dequantize_kv, quantize_kv
         kv_name = "int8" if pcache["k_pages"].dtype == jnp.int8 else "fp8"
+    if has_attn(cfg):
+        bs = pcache["k_pages"].shape[3]
+        # each slot's current block, and its row there
+        blk = jnp.take_along_axis(
+            tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1)[:, None],
+            axis=1)[:, 0]
+        at_row = (jnp.arange(bs) == (pos % bs)[:, None])[:, None, :, None]
 
     def body(carry, xs):
         x, kp_all, vp_all, ks_all, vs_all = carry
@@ -379,45 +405,18 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
         delta = 0.0
         new_st, new_cv = st, cv
         if has_attn(cfg):
-            with jax.named_scope("kv_gather"):   # this layer's pool
-                kp = lax.dynamic_index_in_dim(kp_all, li, 0, keepdims=False)
-                vp = lax.dynamic_index_in_dim(vp_all, li, 0, keepdims=False)
-                ks = vs = None
-                if quant:
-                    ks = lax.dynamic_index_in_dim(ks_all, li, 0,
-                                                  keepdims=False)
-                    vs = lax.dynamic_index_in_dim(vs_all, li, 0,
-                                                  keepdims=False)
-            a_out, (k_new, v_new) = L.attention_decode_paged(
-                bp["attn"], cfg, h, kp, vp, tables, pos, window=win,
-                k_scales=ks, v_scales=vs, sparse_threshold=sparse_threshold)
-            # persist only each slot's new row into its current block (a
-            # per-slot scatter; the pool slab never round-trips per layer)
+            with jax.named_scope("attn_proj"):
+                q, k_new, v_new = L.attention_qkv(bp["attn"], cfg, h,
+                                                  pos[:, None])
             with jax.named_scope("kv_append"):
-                bs = kp_all.shape[2]
-                blk = jnp.take_along_axis(
-                    tables,
-                    jnp.clip(pos // bs, 0, tables.shape[1] - 1)[:, None],
-                    axis=1)[:, 0]
-                if quant:
-                    # read-modify-write requant of each slot's current block
-                    row = jnp.arange(B)
-                    kf = dequantize_kv(kp[blk], ks[blk])  # (B, bs, Hkv, D)
-                    vf = dequantize_kv(vp[blk], vs[blk])
-                    kf = kf.at[row, pos % bs].set(
-                        k_new[:, 0].astype(kf.dtype))
-                    vf = vf.at[row, pos % bs].set(
-                        v_new[:, 0].astype(vf.dtype))
-                    kq, ksb = quantize_kv(kf, kv_name)
-                    vq, vsb = quantize_kv(vf, kv_name)
-                    kp_all = kp_all.at[li, blk].set(kq)
-                    vp_all = vp_all.at[li, blk].set(vq)
-                    ks_all = ks_all.at[li, blk].set(ksb)
-                    vs_all = vs_all.at[li, blk].set(vsb)
-                else:
-                    kp_all = kp_all.at[li, blk, pos % bs].set(k_new[:, 0])
-                    vp_all = vp_all.at[li, blk, pos % bs].set(v_new[:, 0])
-            delta = delta + a_out
+                kp_all, ks_all = _append_block(kp_all, ks_all, li, blk,
+                                               at_row, k_new, kv_name)
+                vp_all, vs_all = _append_block(vp_all, vs_all, li, blk,
+                                               at_row, v_new, kv_name)
+            delta = delta + L.attention_decode_paged(
+                bp["attn"], q, kp_all, vp_all, li, tables, pos, window=win,
+                k_scales=ks_all, v_scales=vs_all,
+                sparse_threshold=sparse_threshold)
         if has_ssm(cfg):
             s_out, sc = SSM.ssm_decode(bp["ssm"], cfg, h,
                                        {"state": st, "conv": cv})
@@ -439,11 +438,10 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
 
     Lc = cfg.n_layers
     dummy = jnp.zeros((Lc, 0), _dtype(cfg))
-    dummy2 = jnp.zeros((0,), _dtype(cfg))
-    kp = pcache.get("k_pages", dummy2)
-    vp = pcache.get("v_pages", dummy2)
-    ks = pcache.get("k_scales", dummy2)
-    vs = pcache.get("v_scales", dummy2)
+    kp = pcache.get("k_pages")
+    vp = pcache.get("v_pages")
+    ks = pcache.get("k_scales")
+    vs = pcache.get("v_scales")
     st = pcache.get("ssm_state", dummy)
     cv = pcache.get("ssm_conv", dummy)
     lidx = jnp.arange(Lc, dtype=jnp.int32)

@@ -23,8 +23,9 @@ Two layers live here:
     blocks (see "Prefix caching" below and ``docs/prefix_caching.md``).
   * jnp page helpers — ``init_pages`` / ``write_prefix_pages`` create and
     fill the device-resident page arrays
-    ``(L, n_blocks, block_size, Hkv, D)`` at prefill time.  The decode-time
-    hot path (per-token append + gather) lives in
+    ``(L, n_blocks, Hkv, block_size, D)`` (head-major blocks) at prefill
+    time.  The decode-time hot path (whole-block append, then the gather)
+    lives in ``models.transformer.decode_step_paged`` and
     ``models.layers.attention_decode_paged``; the Pallas kernel in
     ``repro.kernels.paged_attention`` streams the same layout without the
     dense gather.
@@ -367,7 +368,7 @@ def _kv_qspec(kv_dtype: str):
 def quantize_kv(x, kv_dtype: str):
     """Per-block-per-head abs-max quantization of KV rows.
 
-    ``x``: ``(..., block_size, Hkv, D)`` float rows (any leading axes).
+    ``x``: ``(..., Hkv, block_size, D)`` float rows (any leading axes).
     Returns ``(q, scales)`` with ``q`` the same shape packed to the target
     dtype and ``scales`` shaped ``(..., Hkv)`` float32, one scale per
     (leading..., kv-head) tile — the whole ``(block_size, D)`` extent of a
@@ -380,29 +381,33 @@ def quantize_kv(x, kv_dtype: str):
 
     qdt, qmax = _kv_qspec(kv_dtype)
     xf = x.astype(jnp.float32)
-    amax = jnp.max(jnp.abs(xf), axis=(-3, -1))
+    amax = jnp.max(jnp.abs(xf), axis=(-2, -1))
     # floor keeps an all-zero block from dividing by zero; any real row's
     # abs-max dominates it, so the error bound is untouched
     scales = jnp.maximum(amax / qmax, 1e-12)
-    q = xf / scales[..., None, :, None]
+    q = xf / scales[..., None, None]
     if qdt == jnp.int8:
         q = jnp.clip(jnp.round(q), -qmax, qmax)
     return q.astype(qdt), scales
 
 
 def dequantize_kv(q, scales, dtype=None):
-    """Inverse of ``quantize_kv``: ``q (..., bs, Hkv, D)`` packed values +
+    """Inverse of ``quantize_kv``: ``q (..., Hkv, bs, D)`` packed values +
     ``scales (..., Hkv)`` back to float (``dtype`` or float32)."""
     import jax.numpy as jnp
 
-    x = q.astype(jnp.float32) * scales.astype(jnp.float32)[..., None, :, None]
+    x = q.astype(jnp.float32) * scales.astype(jnp.float32)[..., None, None]
     return x if dtype is None else x.astype(dtype)
 
 
 def init_pages(cfg, n_blocks: int, block_size: int, dtype=None, *,
                kv_dtype: str = "fp32") -> Dict:
-    """Page arrays ``k/v: (L, n_blocks, block_size, Hkv, D)``; empty dict for
+    """Page arrays ``k/v: (L, n_blocks, Hkv, block_size, D)``; empty dict for
     attention-free families (their recurrent state is per-slot already).
+    Blocks are head-major: each (block, kv-head) tile ``(block_size, D)``
+    is contiguous and tiles as (16, 128) on a TPU whatever the head count,
+    so no configuration pads its pool, and decode reads and appends whole
+    blocks in the order they are stored.
     With a quantized ``kv_dtype`` the pages are packed (int8/fp8) and the
     dict carries ``k_scales``/``v_scales`` ``(L, n_blocks, Hkv)`` float32 —
     their presence is how downstream consumers detect the layout."""
@@ -410,7 +415,7 @@ def init_pages(cfg, n_blocks: int, block_size: int, dtype=None, *,
 
     if cfg.family == "ssm":
         return {}
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim)
+    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     if kv_dtype != "fp32":
         qdt, _ = _kv_qspec(kv_dtype)
         sshape = (cfg.n_layers, n_blocks, cfg.n_kv_heads)
@@ -427,12 +432,13 @@ def write_prefix_pages(pages: Dict, k, v, tables) -> Dict:
     ONE scatter per pool array, however many slots are installed.
 
     k/v: ``(L, B, S, Hkv, D)`` dense rows; ``tables``: ``(B, T)`` int32
-    block chains, null-padded.  Whole blocks are written: positions past a
-    slot's length carry garbage that per-slot length masking hides until
-    decode appends overwrite it, and null-padded table entries land
-    harmlessly in the null block (which no live slot ever reads).  A prefix
-    longer than the table can hold is a caller bug and raises — this module
-    never silently truncates context.
+    block chains, null-padded.  Each block's rows are transposed to the
+    pool's head-major ``(Hkv, bs, D)`` order.  Whole blocks are written:
+    positions past a slot's length carry garbage that per-slot length
+    masking hides until decode appends overwrite it, and null-padded table
+    entries land harmlessly in the null block (which no live slot ever
+    reads).  A prefix longer than the table can hold is a caller bug and
+    raises — this module never silently truncates context.
 
     When ``pages`` carries ``k_scales``/``v_scales`` (a quantized pool from
     ``init_pages(kv_dtype=...)``) this is the quantize-on-append path: each
@@ -443,7 +449,7 @@ def write_prefix_pages(pages: Dict, k, v, tables) -> Dict:
     import jax.numpy as jnp
 
     kp, vp = pages["k_pages"], pages["v_pages"]
-    bs = kp.shape[2]
+    bs = kp.shape[3]
     L, B, S, Hkv, D = k.shape
     T = tables.shape[1]
     pad = T * bs - S
@@ -453,8 +459,8 @@ def write_prefix_pages(pages: Dict, k, v, tables) -> Dict:
             f"{T * bs} (T={T} blocks x block_size={bs}); the pool never "
             "silently truncates context")
     widths = ((0, 0), (0, 0), (0, pad), (0, 0), (0, 0))
-    k_blk = jnp.pad(k, widths).reshape(L, B * T, bs, Hkv, D)
-    v_blk = jnp.pad(v, widths).reshape(L, B * T, bs, Hkv, D)
+    k_blk = jnp.pad(k, widths).reshape(L, B * T, bs, Hkv, D).swapaxes(2, 3)
+    v_blk = jnp.pad(v, widths).reshape(L, B * T, bs, Hkv, D).swapaxes(2, 3)
     idx = jnp.asarray(tables, jnp.int32).reshape(-1)
     if "k_scales" in pages:
         name = "int8" if kp.dtype == jnp.int8 else "fp8"

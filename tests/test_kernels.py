@@ -108,9 +108,9 @@ def test_flash_decode_matches_model_decode():
 
 def _paged_pool(rng, B, T, N, bs, Hkv, D, dtype, lens):
     """Random pool + per-slot block tables covering ``lens`` tokens each."""
-    kp = jax.random.normal(jax.random.PRNGKey(3), (N, bs, Hkv, D),
+    kp = jax.random.normal(jax.random.PRNGKey(3), (N, Hkv, bs, D),
                           jnp.float32).astype(dtype)
-    vp = jax.random.normal(jax.random.PRNGKey(4), (N, bs, Hkv, D),
+    vp = jax.random.normal(jax.random.PRNGKey(4), (N, Hkv, bs, D),
                           jnp.float32).astype(dtype)
     free = list(rng.permutation(np.arange(1, N)))
     tables = np.zeros((B, T), np.int32)
@@ -156,11 +156,43 @@ def test_paged_ref_matches_dense_decode():
     kp, vp, tables = _paged_pool(rng, B, T, N, bs, Hkv, D, jnp.float32, lens)
     q = jax.random.normal(jax.random.PRNGKey(6), (B, 1, Hq, D))
     cur = jnp.asarray([l - 1 for l in lens], jnp.int32)
-    kd = kp[tables].reshape(B, T * bs, Hkv, D)
-    vd = vp[tables].reshape(B, T * bs, Hkv, D)
+    kd = kp[tables].swapaxes(2, 3).reshape(B, T * bs, Hkv, D)
+    vd = vp[tables].swapaxes(2, 3).reshape(B, T * bs, Hkv, D)
     out_p = pa_ref.paged_decode_attention(q[:, 0], kp, vp, tables, cur)
     out_d = model_decode(q, kd, vd, cur)[:, 0]
     np.testing.assert_allclose(np.asarray(out_p), np.asarray(out_d),
+                               rtol=2e-4, atol=2e-4)
+
+
+@pytest.mark.parametrize("Hq,Hkv,window,threshold", [
+    (4, 2, 0, 0.0),       # GQA
+    (4, 4, 0, 0.0),       # MHA
+    (4, 2, 20, 0.0),      # sliding window
+    (4, 2, 0, 0.1),       # blockwise-sparse
+])
+def test_block_attention_matches_oracles(Hq, Hkv, window, threshold):
+    """The model's attention over gathered head-major blocks equals the
+    paged oracles, which flatten the blocks into one sequence first."""
+    from repro.kernels.paged_attention import ref as pa_ref
+    from repro.models.layers import block_attention
+    rng = np.random.default_rng(5)
+    B, D, bs, T, N = 2, 32, 8, 4, 12
+    lens = [13, 27]
+    kp, vp, tables = _paged_pool(rng, B, T, N, bs, Hkv, D, jnp.float32, lens)
+    q = jax.random.normal(jax.random.PRNGKey(10), (B, 1, Hq, D))
+    cur = jnp.asarray([l - 1 for l in lens], jnp.int32)
+    keep = None
+    if threshold:
+        keep = pa_ref.block_keep_mask(q[:, 0], kp, tables, cur,
+                                      threshold=threshold, window=window)
+        want = pa_ref.paged_decode_attention_sparse(
+            q[:, 0], kp, vp, tables, cur, threshold=threshold, window=window)
+    else:
+        want = pa_ref.paged_decode_attention(q[:, 0], kp, vp, tables, cur,
+                                             window=window)
+    got = block_attention(q, kp[tables], vp[tables], cur,
+                          window=window or None, block_keep=keep)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=2e-4, atol=2e-4)
 
 

@@ -99,8 +99,8 @@ def test_write_prefix_pages_rejects_overflow():
     from repro.serving.kv_pool import write_prefix_pages
 
     L, B, Hkv, D, bs, T = 1, 1, 1, 2, 4, 2
-    pages = {"k_pages": jnp.zeros((L, 8, bs, Hkv, D)),
-             "v_pages": jnp.zeros((L, 8, bs, Hkv, D))}
+    pages = {"k_pages": jnp.zeros((L, 8, Hkv, bs, D)),
+             "v_pages": jnp.zeros((L, 8, Hkv, bs, D))}
     tables = jnp.asarray([[1, 2]], jnp.int32)
     good = jnp.ones((L, B, T * bs, Hkv, D))
     write_prefix_pages(pages, good, good, tables)   # exactly full: fine
@@ -122,12 +122,12 @@ def test_quantize_round_trip_int8_bound():
     from repro.serving.kv_pool import dequantize_kv, quantize_kv
 
     rng = np.random.default_rng(0)
-    x = jnp.asarray(rng.standard_normal((3, 8, 2, 16)) * 5.0, jnp.float32)
+    x = jnp.asarray(rng.standard_normal((3, 2, 8, 16)) * 5.0, jnp.float32)
     q, s = quantize_kv(x, "int8")
     assert q.dtype == jnp.int8 and q.shape == x.shape
     assert s.shape == (3, 2) and s.dtype == jnp.float32
     err = np.abs(np.asarray(dequantize_kv(q, s)) - np.asarray(x))
-    bound = np.asarray(s)[:, None, :, None] * (0.5 + 1e-5)
+    bound = np.asarray(s)[:, :, None, None] * (0.5 + 1e-5)
     assert (err <= bound).all()
     # all-zero input: the scale floor keeps the round-trip exact
     zq, zs = quantize_kv(jnp.zeros_like(x), "int8")
@@ -146,11 +146,11 @@ def test_quantize_round_trip_property(seed, amp, nblk, hkv, d):
     from repro.serving.kv_pool import dequantize_kv, quantize_kv
 
     rng = np.random.default_rng(seed)
-    x = jnp.asarray(rng.standard_normal((nblk, 4, hkv, d)) * amp,
+    x = jnp.asarray(rng.standard_normal((nblk, hkv, 4, d)) * amp,
                     jnp.float32)
     q, s = quantize_kv(x, "int8")
     err = np.abs(np.asarray(dequantize_kv(q, s)) - np.asarray(x))
-    bound = np.asarray(s)[:, None, :, None] * (0.5 + 1e-5) + 1e-9
+    bound = np.asarray(s)[:, :, None, None] * (0.5 + 1e-5) + 1e-9
     assert (err <= bound).all()
 
 
@@ -163,8 +163,8 @@ def test_write_prefix_pages_quantized_scatter():
     from repro.serving.kv_pool import dequantize_kv, write_prefix_pages
 
     L, B, Hkv, D, bs, T, N = 2, 1, 2, 4, 4, 2, 8
-    pages = {"k_pages": jnp.zeros((L, N, bs, Hkv, D), jnp.int8),
-             "v_pages": jnp.zeros((L, N, bs, Hkv, D), jnp.int8),
+    pages = {"k_pages": jnp.zeros((L, N, Hkv, bs, D), jnp.int8),
+             "v_pages": jnp.zeros((L, N, Hkv, bs, D), jnp.int8),
              "k_scales": jnp.zeros((L, N, Hkv), jnp.float32),
              "v_scales": jnp.zeros((L, N, Hkv), jnp.float32)}
     tables = jnp.asarray([[2, 5]], jnp.int32)
@@ -176,11 +176,11 @@ def test_write_prefix_pages_quantized_scatter():
     out = write_prefix_pages(pages, k, v, tables)
     assert out["k_pages"].dtype == jnp.int8
     for pk, sk, src in (("k_pages", "k_scales", k), ("v_pages", "v_scales", v)):
-        blk = out[pk][:, tables[0]]               # (L, T, bs, Hkv, D)
+        blk = out[pk][:, tables[0]]               # (L, T, Hkv, bs, D)
         scl = out[sk][:, tables[0]]               # (L, T, Hkv)
         got = np.asarray(dequantize_kv(blk, scl))
-        want = np.asarray(src).reshape(L, T, bs, Hkv, D)
-        bound = np.asarray(scl)[:, :, None, :, None] * (0.5 + 1e-5) + 1e-9
+        want = np.asarray(src).reshape(L, T, bs, Hkv, D).swapaxes(2, 3)
+        bound = np.asarray(scl)[..., None, None] * (0.5 + 1e-5) + 1e-9
         assert (np.abs(got - want) <= bound).all()
         untouched = np.ones(N, bool)
         untouched[[2, 5]] = False

@@ -79,6 +79,62 @@ def test_paged_decode_logits_match_dense_oracle(built):
     assert ep.pool.n_live == 0  # every block returned to the pool
 
 
+@pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
+def test_decode_step_appends_one_row_per_active_slot(kv_dtype):
+    """One decode step changes the pool only where it appends: in each
+    layer, the one row at each active slot's write position inside its
+    current block (and the null block, which the idle slot writes).  Every
+    other row and block is bit-identical.  A bf16 pool keeps the current
+    block's other rows bit-identical too; an int8 pool requantizes the
+    whole block with a fresh scale, so they move by at most half of it."""
+    import jax
+    from repro.models import api as mapi
+    from repro.serving.kv_pool import NULL_BLOCK, dequantize_kv
+
+    cfg = get_config("qwen2-7b", smoke=True)           # bf16 weights and KV
+    m = mapi.build(cfg)
+    params = m.init(jax.random.PRNGKey(1))
+    bs = 8
+    eng = PartitionEngine(cfg, m, params, slots=3, max_len=48,
+                          peak_flops=hw.TPU_PEAK_FLOPS, paged=True,
+                          block_size=bs, kv_dtype=kv_dtype)
+    q = RequestQueue()
+    _load(q, [8, 12], vocab=cfg.vocab)      # one block start, one mid-block
+    eng.assign(q.pop(2))
+    eng.prefill_wave(0.0)
+    before = {k: np.asarray(v) for k, v in eng.pages.items()}
+    rows = {}                               # current block -> written row
+    for i in range(2):
+        pos = eng.slot_lens[i]
+        rows[eng.slot_tables[i][pos // bs]] = pos % bs
+    assert sorted(rows.values()) == [0, 4]
+    eng.decode_step(0.0)
+    after = {k: np.asarray(v) for k, v in eng.pages.items()}
+
+    untouched = np.ones(before["k_pages"].shape[1], bool)
+    untouched[list(rows) + [NULL_BLOCK]] = False
+    for name in ("k", "v"):
+        pb, pa = before[f"{name}_pages"], after[f"{name}_pages"]
+        np.testing.assert_array_equal(pa[:, untouched], pb[:, untouched])
+        for blk, r in rows.items():
+            other = np.arange(bs) != r
+            # (L, Hkv, D): the new row differs in every layer
+            assert (pa[:, blk, :, r] != pb[:, blk, :, r]).any(axis=(1, 2)).all()
+            if kv_dtype == "fp32":
+                np.testing.assert_array_equal(pa[:, blk][:, :, other],
+                                              pb[:, blk][:, :, other])
+                continue
+            sb = before[f"{name}_scales"][:, blk]            # (L, Hkv)
+            sa = after[f"{name}_scales"][:, blk]
+            np.testing.assert_array_equal(
+                after[f"{name}_scales"][:, untouched],
+                before[f"{name}_scales"][:, untouched])
+            old = np.asarray(dequantize_kv(pb[:, blk], sb))[:, :, other]
+            new = np.asarray(dequantize_kv(pa[:, blk], sa))[:, :, other]
+            bound = sa[:, :, None, None] * (0.5 + 1e-5) + 1e-9
+            assert (np.abs(new - old) <= bound).all()
+
+
 def test_mixed_length_wave_serves_instead_of_raising():
     """The seed engine raised ``ValueError: mixed prompt lengths in one
     prefill wave``; per-slot lengths make the same load a normal wave."""

@@ -13,6 +13,7 @@ process may load the TPU library, and each test worker imports every test
 file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -32,6 +33,12 @@ HBM_BYTES = 16e9
 WIDTHS = {"qwen1.5-4b": (20, 20, 128), "qwen2-7b": (28, 4, 128)}
 # the chip smoke load: 4 slots, prompt 512, budget 512 + 4 * 64
 SLOTS, PROMPT, MAX_LEN, BLOCK = 4, 512, 768, 16
+# the served decode step at the chip benchmark's serving sizes: layers kept
+# (None: all), slots, max_len, and a bound on the step's HBM temporaries
+DECODE_SERVED = {"qwen1.5-4b": (None, 8, 640, 64e6),
+                 "qwen2-7b": (7, 32, 3200, None)}
+_COPY = re.compile(r"= (\w+)\[([\d,]*)\]\{[^}]*\} copy\(")
+_BYTES = {"bf16": 2, "f16": 2, "s8": 1, "u8": 1, "f8e4m3fn": 1, "pred": 1}
 
 
 @pytest.fixture(scope="module")
@@ -112,7 +119,7 @@ def test_qwen4b_prefill_compiles(qwen4b, one_chip):
 def test_qwen4b_decode_paged_compiles(qwen4b, one_chip):
     cfg, api, params = qwen4b
     n_blocks = 1 + SLOTS * MAX_LEN // BLOCK
-    pages = _spec(one_chip, (cfg.n_layers, n_blocks, BLOCK, cfg.n_kv_heads,
+    pages = _spec(one_chip, (cfg.n_layers, n_blocks, cfg.n_kv_heads, BLOCK,
                              cfg.head_dim))
     pcache = {"k_pages": pages, "v_pages": pages,
               "tables": _spec(one_chip, (SLOTS, MAX_LEN // BLOCK), jnp.int32),
@@ -121,6 +128,49 @@ def test_qwen4b_decode_paged_compiles(qwen4b, one_chip):
     step = jax.jit(api.decode_paged, donate_argnums=(2,))
     m = _fits(step.lower(params, token, pcache).compile())
     assert m.alias_size_in_bytes >= 2 * pages.size * 2   # pool updated in place
+
+
+def _copies(hlo: str, at_least: int) -> list:
+    """Every ``copy`` in compiled HLO whose result holds ``at_least``
+    bytes or more."""
+    out = []
+    for dt, dims in _COPY.findall(hlo):
+        n = _BYTES.get(dt, 4)
+        for d in filter(None, dims.split(",")):
+            n *= int(d)
+        if n >= at_least:
+            out.append((dt, dims, n))
+    return out
+
+
+@pytest.mark.parametrize("model", sorted(DECODE_SERVED))
+def test_decode_paged_reads_pool_in_place(model, one_chip):
+    """The decode step reads and appends the pool in the order it is
+    stored: no copy as large as one layer's K pool (a relayout of the pool
+    or of a layer's slice), bounded temporaries, the pool aliased."""
+    from repro.serving.kv_pool import init_pages
+    layers, slots, max_len, temp_limit = DECODE_SERVED[model]
+    cfg = get_config(model)
+    if layers:
+        cfg = cfg.replace(n_layers=layers)
+    api = mapi.build(cfg)
+    params = _on_chip(jax.eval_shape(api.init, jax.random.PRNGKey(0)),
+                      one_chip)
+    n_blocks = 1 + slots * max_len // BLOCK
+    pcache = _on_chip(jax.eval_shape(
+        lambda: init_pages(cfg, n_blocks, BLOCK)), one_chip)
+    pcache["tables"] = _spec(one_chip, (slots, max_len // BLOCK), jnp.int32)
+    pcache["lens"] = _spec(one_chip, (slots,), jnp.int32)
+    token = _spec(one_chip, (slots, 1), jnp.int32)
+    step = jax.jit(api.decode_paged, donate_argnums=(2,))
+    compiled = step.lower(params, token, pcache).compile()
+    m = _fits(compiled)
+    pool = pcache["k_pages"]
+    layer_bytes = pool.size // cfg.n_layers * pool.dtype.itemsize
+    assert _copies(compiled.as_text(), layer_bytes) == []
+    if temp_limit:
+        assert m.temp_size_in_bytes < temp_limit, m.temp_size_in_bytes
+    assert m.alias_size_in_bytes >= 2 * pool.size * pool.dtype.itemsize
 
 
 # ---------------------------------------------------------------------------
@@ -137,13 +187,13 @@ def test_paged_decode_compiles(model, variant, one_chip):
     tables = _spec(one_chip, (SLOTS, t), jnp.int32)
     cur = _spec(one_chip, (SLOTS,), jnp.int32)
     if variant == "int8":
-        pages = _spec(one_chip, (n, BLOCK, Hkv, D), jnp.int8)
+        pages = _spec(one_chip, (n, Hkv, BLOCK, D), jnp.int8)
         scales = _spec(one_chip, (n, Hkv), jnp.float32)
         _compiled_kernel(
             lambda *a: pa_ops.paged_decode_quant(*a, interpret=False),
             q, pages, pages, scales, scales, tables, cur)
         return
-    pages = _spec(one_chip, (n, BLOCK, Hkv, D))
+    pages = _spec(one_chip, (n, Hkv, BLOCK, D))
     if variant == "sparse":
         fn = lambda *a: pa_ops.paged_decode_sparse(  # noqa: E731
             *a, threshold=0.05, interpret=False)
