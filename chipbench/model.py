@@ -1,15 +1,16 @@
 """A configuration file, its weights, and the engine that serves them.
 
 ``load_config`` reads ``configs/<name>.json`` (the published ``config.json``
-keys plus the serving sizes) and ``program_config`` maps it onto the
-program's ``ModelConfig``.  ``make_params`` draws the weights from the seed
-in one jitted call on the device, in the program's parameter layout and in
-the dtype they are served in; the benchmark makes them, not the program, so
-that the reference can read them without reading anything the program made.
-``build_engine`` mirrors ``repro.launch.serve.build_fleet`` with its
-defaults (one ``PartitionEngine`` with the paged pool, the ``uniform``
-policy on the event clock) for a configuration that the CLI's registry does
-not hold.
+keys plus the serving sizes); its ``model_type`` names the architecture
+module (``arch/<model_type>.py``) that says its sizes, its parameter layout
+and its mapping onto the program's ``ModelConfig``.  ``make_params`` draws
+the weights from the seed in one jitted call on the device, in the
+program's parameter layout and in the dtype they are served in; the
+benchmark makes them, not the program, so that the reference can read them
+without reading anything the program made.  ``build_engine`` mirrors
+``repro.launch.serve.build_fleet`` with its defaults (one
+``PartitionEngine`` with the paged pool, the ``uniform`` policy on the
+event clock) for a configuration that the CLI's registry does not hold.
 """
 from __future__ import annotations
 
@@ -18,6 +19,8 @@ from dataclasses import dataclass
 from pathlib import Path
 
 import numpy as np
+
+from chipbench import arch
 
 HERE = Path(__file__).resolve().parent
 
@@ -30,75 +33,6 @@ def load_config(name: str) -> dict:
     return doc
 
 
-@dataclass(frozen=True)
-class Dims:
-    """The sizes the harness itself counts with (work, bytes, reference)."""
-    layers: int
-    d: int
-    heads: int
-    kv_heads: int
-    head_dim: int
-    d_ff: int
-    vocab: int
-    rope_theta: float
-    eps: float
-    tied: bool
-
-    @property
-    def kv_bytes_per_token(self) -> int:
-        """bf16 K and V of one position over every layer."""
-        return 2 * 2 * self.layers * self.kv_heads * self.head_dim
-
-    @property
-    def layer_params(self) -> int:
-        d, hd = self.d, self.head_dim
-        attn = d * self.heads * hd * 2 + d * self.kv_heads * hd * 2
-        bias = self.heads * hd + 2 * self.kv_heads * hd
-        return attn + bias + 3 * d * self.d_ff + 2 * d
-
-    @property
-    def matmul_params(self) -> int:
-        """Parameters a token multiplies by: every layer's projections and
-        the LM head (the embedding is a lookup)."""
-        d, hd = self.d, self.head_dim
-        attn = d * self.heads * hd * 2 + d * self.kv_heads * hd * 2
-        return self.layers * (attn + 3 * d * self.d_ff) + d * self.vocab
-
-    @property
-    def params(self) -> int:
-        head = 0 if self.tied else self.d * self.vocab
-        return self.layers * self.layer_params + self.vocab * self.d + head \
-            + self.d
-
-
-def dims(doc: dict) -> Dims:
-    heads = doc["num_attention_heads"]
-    return Dims(layers=doc["num_hidden_layers"], d=doc["hidden_size"],
-                heads=heads, kv_heads=doc["num_key_value_heads"],
-                head_dim=doc.get("head_dim") or doc["hidden_size"] // heads,
-                d_ff=doc["intermediate_size"], vocab=doc["vocab_size"],
-                rope_theta=float(doc["rope_theta"]),
-                eps=float(doc["rms_norm_eps"]),
-                tied=bool(doc["tie_word_embeddings"]))
-
-
-def program_config(doc: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
-    from repro.configs.base import ModelConfig
-
-    if doc.get("model_type") != "qwen2" or doc.get("hidden_act") != "silu":
-        raise ValueError(f"{doc['_name']}: only qwen2-type SwiGLU models "
-                         "are mapped")
-    m = dims(doc)
-    return ModelConfig(
-        name=doc["_name"], family="dense", n_layers=m.layers, d_model=m.d,
-        n_heads=m.heads, n_kv_heads=m.kv_heads, d_ff=m.d_ff, vocab=m.vocab,
-        d_head=m.head_dim, qkv_bias=True, rope_theta=m.rope_theta,
-        norm_eps=m.eps, act="silu", tie_embeddings=m.tied,
-        dtype=doc["torch_dtype"],
-        **doc.get("program", {}))
-
-
 def seed_key(seed: int):
     """A JAX key from any whole number, also one past 2**31."""
     import jax
@@ -107,50 +41,15 @@ def seed_key(seed: int):
     return jax.random.fold_in(jax.random.key(int(lo) & 0x7FFFFFFF), int(hi))
 
 
-def param_shapes(m: Dims) -> dict:
-    """The program's parameter layout (``models.transformer.init_lm``):
-    per-layer leaves stacked on a leading layer axis."""
-    L, d, hd = m.layers, m.d, m.head_dim
-    q, kv = m.heads * hd, m.kv_heads * hd
-    shapes = {
-        "embed": (m.vocab, d),
-        "blocks": {
-            "ln1": (L, d), "ln2": (L, d),
-            "attn": {"wq": (L, d, q), "wk": (L, d, kv), "wv": (L, d, kv),
-                     "wo": (L, q, d), "bq": (L, q), "bk": (L, kv),
-                     "bv": (L, kv)},
-            "mlp": {"w1": (L, d, m.d_ff), "w3": (L, d, m.d_ff),
-                    "w2": (L, m.d_ff, d)},
-        },
-        "ln_f": (d,),
-    }
-    if not m.tied:
-        shapes["lm_head"] = (d, m.vocab)
-    return shapes
-
-
-def _leaf_scale(path: str, shape) -> tuple[float, float]:
-    """(mean, half-width) of the uniform draw for one leaf: matrices keep
-    unit-variance activations (1/sqrt(fan_in)), biases and norm gains are
-    small perturbations so that their code paths change the result."""
-    leaf = path.rsplit("/", 1)[-1]
-    if leaf in ("ln1", "ln2", "ln_f"):
-        return 1.0, 0.2
-    if leaf.startswith("b"):
-        return 0.0, 0.2
-    if leaf == "embed":
-        return 0.0, np.sqrt(3.0)
-    fan_in = shape[-2]
-    return 0.0, float(np.sqrt(3.0 / fan_in))
-
-
-def make_params(m: Dims, seed: int, dtype="bfloat16"):
-    """Every weight drawn from ``seed`` in one jitted call on the default
-    device: uniform draws of the stated scale, in the served dtype."""
+def make_params(m, seed: int, dtype="bfloat16"):
+    """Every weight of ``m``'s architecture drawn from ``seed`` in one
+    jitted call on the default device: uniform draws of the scale it
+    states, in the served dtype."""
     import jax
     import jax.numpy as jnp
 
-    shapes = param_shapes(m)
+    mod = arch.of(m)
+    shapes = mod.param_shapes(m)
     flat, tree = jax.tree.flatten_with_path(
         shapes, is_leaf=lambda x: isinstance(x, tuple))
     specs = [("/".join(str(getattr(k, "key", k)) for k in path), shape)
@@ -162,7 +61,7 @@ def make_params(m: Dims, seed: int, dtype="bfloat16"):
         keys = jax.random.split(key, len(specs))
         out = []
         for k, (path, shape) in zip(keys, specs):
-            mean, half = _leaf_scale(path, shape)
+            mean, half = mod.leaf_scale(path, shape)
             out.append(jax.random.uniform(k, shape, dt, mean - half,
                                           mean + half))
         return jax.tree.unflatten(tree, out)
@@ -189,7 +88,7 @@ def build_engine(doc: dict, params) -> Served:
     from repro.models import api as mapi
     from repro.serving import PartitionEngine, RequestQueue, make_scheduler
 
-    cfg = program_config(doc)
+    cfg = arch.arch_for(doc).program_config(doc)
     api = mapi.build(cfg)
     want = jax.eval_shape(api.init, jax.random.PRNGKey(0))
     got = jax.tree.map(lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),

@@ -457,7 +457,7 @@ def profile_cell(spec: dict, *, seed: int, seconds: float,
     end-to-end numbers, and the ``ProgramView`` they were read from.
     ``keep(xplane_path, hlo_text)`` sees the raw trace before it is
     deleted (the trace fixture is recorded so)."""
-    from chipbench import model, run
+    from chipbench import arch, run
     from chipbench import trace as trace_mod
     from repro.obs import Tracer, WallClock
 
@@ -490,7 +490,7 @@ def profile_cell(spec: dict, *, seed: int, seconds: float,
         win, t_close=win.t_span_close,
         sent=[r for r in win.sent if rec.due[r] < win.t_span_close])
     view = ProgramView(
-        m=model.dims(spec["config"]), peaks=None,
+        m=arch.arch_for(spec["config"]).dims(spec["config"]), peaks=None,
         slots=int(spec["config"]["serving"]["slots"]), rec=rec,
         window=traced, trace=trace_mod.reduce(box["trace"]), tracer=tracer,
         host=box["trace"].host, decode_ops=box["ops"],

@@ -1,33 +1,28 @@
-"""The plain float32 reference of a Qwen2-type decoder, and its controls.
-
-Written from the published architecture (Qwen2ForCausalLM): RMSNorm,
-attention with a bias on q/k/v and none on o, rotary embeddings on the
-halves of each head (``rotate_half``), grouped KV heads, a SwiGLU MLP
-(``down(silu(gate(x)) * up(x))``), a final RMSNorm and an untied LM head.
-It imports nothing of the program and takes only the weights the benchmark
-drew, in the parameter layout of ``model.param_shapes`` (``w1`` is the
-gate, ``w3`` the up projection, ``w2`` the down projection).
+"""The plain float32 reference of a served model, and its controls.
 
 ``score`` runs one sequence (prompt followed by the tokens that were served
-for it) through the layers one at a time, in float32 at ``HIGHEST``
-precision, and returns at every scored position the reference's best logit
-and its logit for the served token.  With a ``control`` it also runs the
-same forward with every matmul operand (weights, activations, q, k, v and
-the attention probabilities) rounded to a lower precision with one scale
-per tensor: ``"fp8"`` (float8 e4m3) or ``"int8"`` (symmetric, 127 steps),
-the steps below bfloat16 that a later change could be tempted to take.  It
-returns the token that this forward puts first, its own logit for it, and
-the reference's logit for it: the control put in the program's place.
+for it) through the forward of the model's architecture
+(``arch.<model_type>.hidden`` and ``head``, written from the published
+architecture, importing nothing of the program and taking only the weights
+the benchmark drew) in float32 at ``HIGHEST`` precision, and returns at
+every scored position the reference's best logit and its logit for the
+served token.  With a ``control`` it also runs the same forward with every
+matmul operand (weights, activations, q, k, v and the attention
+probabilities) rounded to a lower precision with one scale per tensor
+(``_low``, ``_mm``): ``"fp8"`` (float8 e4m3) or ``"int8"`` (symmetric, 127
+steps), the steps below bfloat16 that a later change could be tempted to
+take.  It returns the token that this forward puts first, its own logit
+for it, and the reference's logit for it: the control put in the
+program's place.  The rounding is shared, so that a control means the same
+for every architecture.
 """
 from __future__ import annotations
 
-import math
-from functools import partial
-
-import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+
+from chipbench import arch
 
 HIGHEST = lax.Precision.HIGHEST
 FP8_MAX = 448.0   # largest finite float8 e4m3fn
@@ -59,65 +54,6 @@ def _rms(x, g, eps):
     return x * (1.0 / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + eps)) * g
 
 
-def _rope(x, pos, theta):
-    """x: (S, H, D) with the rotation applied to the two halves of D."""
-    D = x.shape[-1]
-    inv = 1.0 / (theta ** (np.arange(0, D, 2, dtype=np.float64) / D))
-    ang = pos[:, None].astype(jnp.float32) * jnp.asarray(inv, jnp.float32)
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :D // 2], x[..., D // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def _layer(h, w, pos, m, low):
-    S = h.shape[0]
-    H, KV, D = m.heads, m.kv_heads, m.head_dim
-    x = _rms(h, w["ln1"], m.eps)
-    q = (_mm(x, w["wq"], low) + w["bq"]).reshape(S, H, D)
-    k = (_mm(x, w["wk"], low) + w["bk"]).reshape(S, KV, D)
-    v = (_mm(x, w["wv"], low) + w["bv"]).reshape(S, KV, D)
-    q, k = _rope(q, pos, m.rope_theta), _rope(k, pos, m.rope_theta)
-    q = q.reshape(S, KV, H // KV, D)
-    q, k, v = _low(q, low), _low(k, low), _low(v, low)
-    s = jnp.einsum("skgd,tkd->kgst", q, k, precision=HIGHEST) / math.sqrt(D)
-    causal = pos[:, None] >= pos[None, :]
-    s = jnp.where(causal, s, -jnp.inf)
-    p = _low(jax.nn.softmax(s, axis=-1), low)
-    o = jnp.einsum("kgst,tkd->skgd", p, v,
-                   precision=HIGHEST).reshape(S, H * D)
-    h = h + _mm(o, w["wo"], low)
-    x = _rms(h, w["ln2"], m.eps)
-    gate = jax.nn.silu(_mm(x, w["w1"], low))
-    return h + _mm(gate * _mm(x, w["w3"], low), w["w2"], low)
-
-
-def _layer_weights(blocks, i):
-    """Layer ``i`` of the stacked weights, in float32."""
-    flat = {**blocks["attn"], **blocks["mlp"],
-            "ln1": blocks["ln1"], "ln2": blocks["ln2"]}
-    return jax.tree.map(lambda a: a[i].astype(jnp.float32), flat)
-
-
-@partial(jax.jit, static_argnames=("m", "low"))
-def _hidden(params, tokens, m, low):
-    """Final-normed hidden states (S, d) of one padded sequence."""
-    pos = jnp.arange(tokens.shape[0], dtype=jnp.int32)
-    h = params["embed"][tokens].astype(jnp.float32)
-
-    def body(h, i):
-        return _layer(h, _layer_weights(params["blocks"], i), pos, m,
-                      low), None
-
-    h, _ = lax.scan(body, h, jnp.arange(m.layers))
-    return _rms(h, params["ln_f"].astype(jnp.float32), m.eps)
-
-
-@partial(jax.jit, static_argnames=("low",))
-def _head(params, hidden, low):
-    """Logits of ``hidden`` rows (ROWS, d)."""
-    return _mm(hidden, params["lm_head"].astype(jnp.float32), low)
-
-
 def score(params, m, prompt, served, *, length: int,
           control: str | None = None) -> dict:
     """Reference readings at each position that produced a served token.
@@ -141,20 +77,21 @@ def score(params, m, prompt, served, *, length: int,
     n = len(served)
     out = {k: [] for k in ("best", "served", "control_tok", "control_own",
                            "control")}
-    ref = _hidden(params, jnp.asarray(tokens), m, None)
-    low = (_hidden(params, jnp.asarray(tokens), m, control)
+    forward = arch.of(m)
+    ref = forward.hidden(params, jnp.asarray(tokens), m, None)
+    low = (forward.hidden(params, jnp.asarray(tokens), m, control)
            if control else None)
     for a in range(first, first + n, ROWS):
         rows = np.arange(a, a + ROWS)
         valid = rows < first + n
         rows = np.where(valid, rows, first)
-        lg = np.asarray(_head(params, ref[rows], None))[valid]
+        lg = np.asarray(forward.head(params, ref[rows], None))[valid]
         idx = np.arange(len(lg))
         tok = served[rows[valid] - first]
         out["best"].append(lg.max(-1))
         out["served"].append(lg[idx, tok])
         if control:
-            lo = np.asarray(_head(params, low[rows], control))[valid]
+            lo = np.asarray(forward.head(params, low[rows], control))[valid]
             ct = lo.argmax(-1)
             out["control_tok"].append(ct)
             out["control_own"].append(lo[idx, ct])

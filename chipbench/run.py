@@ -59,7 +59,9 @@ class NoChip(RuntimeError):
 
 def load_spec(workload: str, bench_file: Path = ROOT / "BENCHMARK.json"):
     """The cell, its configuration and traffic files, and the metrics it
-    reports, all by name from ``BENCHMARK.json``."""
+    reports, all by name from ``BENCHMARK.json``; the configuration's
+    architecture module (``arch/<model_type>.py``) must exist."""
+    from chipbench.arch import arch_for
     from chipbench.model import load_config
     from chipbench.traffic import load_traffic
 
@@ -73,9 +75,11 @@ def load_spec(workload: str, bench_file: Path = ROOT / "BENCHMARK.json"):
     def mine(metric):
         return workload in metric.get("workloads", [workload])
 
+    config = load_config(cell["config"])
+    arch_for(config)
     return {
         "cell": cell,
-        "config": load_config(cell["config"]),
+        "config": config,
         "traffic": load_traffic(cell["traffic"]),
         "end_to_end": [m for m in bench["end_to_end"] if mine(m)],
         "per_layer": [m for m in bench["per_layer"] if mine(m)],
@@ -164,7 +168,7 @@ def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
     import jax
     import numpy as np
 
-    from chipbench import check, driver, model, stats, traffic
+    from chipbench import arch, check, driver, model, stats, traffic
     from chipbench import peaks as peaks_mod
     from chipbench import trace as trace_mod
     from chipbench.view import RunView
@@ -177,7 +181,7 @@ def run_cell(spec: dict, *, seed: int, seconds: float, trace: bool,
     pk = (peaks_mod.peaks(dev.device_kind) if require_chip
           else peaks_mod.PEAKS["TPU v5 lite"])
     clock = CompileClock()
-    m = model.dims(doc)
+    m = arch.arch_for(doc).dims(doc)
     limits = doc["check"]
 
     # --- set-up: weights, engine, warm-up of every shape the traffic uses

@@ -1,5 +1,6 @@
 """The whole harness on the CPU at a tiny size: a run, its control, the
-faults it must catch, its refusals, and a cell added from data alone."""
+faults it must catch, its refusals, a cell added from data alone and an
+architecture added from new files alone."""
 import json
 import os
 import shutil
@@ -160,3 +161,60 @@ def test_a_new_cell_needs_data_files_and_one_entry(tmp_path):
                        require_chip=False, log=lambda *a: None)
     assert res["correct"] is True
     assert set(res["metrics"]) == {"tokens_per_s", "setup_s"}
+
+
+def _add_cell(root, name: str, **config):
+    """A configuration and a traffic file named ``name`` (the tiny ones,
+    with ``config``'s keys changed) and a ``workloads`` entry for them."""
+    cfg = {k: v for k, v in tiny.CONFIG.items() if k != "_name"}
+    tr = {k: v for k, v in tiny.TRAFFIC.items() if k != "_name"}
+    (root / f"chipbench/configs/{name}.json").write_text(
+        json.dumps({**cfg, **config}))
+    (root / f"chipbench/traffic/{name}.json").write_text(json.dumps(tr))
+    bench = json.loads((root / "BENCHMARK.json").read_text())
+    bench["workloads"].append({"name": name, "config": name,
+                               "traffic": name, "chips": 1,
+                               "why": "a cell added from new files"})
+    (root / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def test_a_new_architecture_needs_only_new_files(tmp_path):
+    """A ``model_type`` that no shared module names runs from new files
+    alone: its module in ``chipbench/arch/`` (here one that re-exports
+    qwen2's), a configuration naming it, a traffic file and a ``workloads``
+    entry.  The run goes through that module and comes out correct."""
+    root = _bench_copy(tmp_path)
+    (root / "chipbench/arch/qwen2_alias.py").write_text(
+        '"""Qwen2 under another model_type."""\n'
+        "from chipbench.arch.qwen2 import *  # noqa: F401,F403\n")
+    _add_cell(root, "tiny_alias", model_type="qwen2_alias")
+    script = (
+        "import json, sys; sys.path[:0] = ['.', sys.argv[1]]; "
+        "from chipbench import arch, run; "
+        "spec = run.load_spec('tiny_alias'); "
+        "res = run.run_cell(spec, seed=5, seconds=0.5, trace=False, "
+        "require_chip=False, log=lambda *a: None); "
+        "mod = arch.arch_for(spec['config']); "
+        "print(json.dumps({'correct': res['correct'], 'file': mod.__file__, "
+        "'checks': res['checks']}))")
+    p = subprocess.run([sys.executable, "-c", script, str(ROOT / "src")],
+                       cwd=root, env=_env(), capture_output=True, text=True,
+                       timeout=600)
+    assert p.returncode == 0, p.stderr[-4000:]
+    out = json.loads(p.stdout.splitlines()[-1])
+    assert Path(out["file"]) == root / "chipbench/arch/qwen2_alias.py"
+    assert out["correct"] is True, out["checks"]
+    assert out["checks"]["sampled_decode_tokens"]["value"] > 0
+
+
+def test_an_unknown_architecture_is_refused_by_name(tmp_path):
+    """A configuration whose ``model_type`` has no module is refused when
+    the cell is looked up, naming the file that was looked for."""
+    root = _bench_copy(tmp_path)
+    _add_cell(root, "tiny_unknown", model_type="no_such_arch")
+    p = subprocess.run(
+        [sys.executable, "chipbench/run.py", "--workload", "tiny_unknown",
+         "--seed", "1", "--seconds", "1"],
+        cwd=root, env=_env(), capture_output=True, text=True, timeout=300)
+    assert p.returncode != 0 and p.stdout.strip() == ""
+    assert str(Path("chipbench", "arch", "no_such_arch.py")) in p.stderr

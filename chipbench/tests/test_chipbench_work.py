@@ -3,12 +3,13 @@ import numpy as np
 import pytest
 
 from chipbench import model, peaks, work
+from chipbench.arch import qwen2
 from chipbench.driver import Op, Recorder, Window
 from chipbench.trace import Reduced
 from chipbench.view import RunView
 
-Q4B = model.dims(model.load_config("qwen1.5-4b"))
-Q7B = model.dims(model.load_config("qwen2-7b.pp4"))
+Q4B = qwen2.dims(model.load_config("qwen1.5-4b"))
+Q7B = qwen2.dims(model.load_config("qwen2-7b.pp4"))
 
 
 def test_parameter_counts():

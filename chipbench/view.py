@@ -18,7 +18,7 @@ MODULE_OF = {"decode": DECODE, "wave": PREFILL[0], "refill": PREFILL[1]}
 
 @dataclass
 class RunView:
-    m: object            # model.Dims
+    m: object            # the architecture's Dims (arch/)
     peaks: object        # peaks.Peaks
     slots: int
     rec: object          # driver.Recorder

@@ -1,23 +1,20 @@
 """The work each executable needs, counted from shapes.
 
 These count what the algorithm needs, whatever implements it, so that a
-roofline share is a share of the least time the chip could take:
-
-  decode   every weight read once per step (the embedding only for the
-           active rows), the live KV of each active slot read once and its
-           new row written, the logits of the active slots written; the
-           matmul and attention FLOPs of the active slots only;
-  prefill  the real prompt tokens, with no padding: weights read once, the
-           prompt's KV written, the last position's logits written; causal
-           attention counted as half the square.
-
-A padded wave or a gather of the whole block table therefore shows as a
-low share, and a kernel that reads only live blocks raises it without
-changing the count.
+roofline share is a share of the least time the chip could take: the
+weights and the KV a call must read and write once each, the FLOPs of the
+real tokens only, no padding.  A padded wave or a gather of the whole
+block table therefore shows as a low share, and a kernel that reads only
+live blocks raises it without changing the count.  What a call of each
+kind needs depends on the architecture, whose module counts it
+(``arch.<model_type>.decode_step`` and ``prefill``; ``arch/qwen2.py``
+says what a dense decoder counts).
 """
 from __future__ import annotations
 
 from dataclasses import dataclass
+
+from chipbench import arch
 
 BF16 = 2
 F32 = 4
@@ -36,36 +33,12 @@ class Work:
         return max(self.flops / peak_flops, self.byts / peak_bytes_per_s)
 
 
-def _weight_bytes(m) -> float:
-    """Every weight but the embedding table."""
-    head = 0 if m.tied else m.d * m.vocab
-    return BF16 * (m.layers * m.layer_params + m.d + head)
-
-
-def _attn_flops(m, kv_pairs: float) -> float:
-    """QK^T and PV over ``kv_pairs`` (query, key) pairs, every layer."""
-    return 4.0 * m.layers * m.heads * m.head_dim * kv_pairs
-
-
 def decode_step(m, ctx: list) -> Work:
     """One decode step; ``ctx`` holds each active slot's context read,
     the new position included."""
-    b = len(ctx)
-    live = float(sum(ctx))
-    flops = 2.0 * m.matmul_params * b + _attn_flops(m, live)
-    byts = (_weight_bytes(m) + BF16 * b * m.d
-            + live * m.kv_bytes_per_token + F32 * b * m.vocab)
-    return Work(flops, byts)
+    return arch.of(m).decode_step(m, ctx)
 
 
 def prefill(m, lens: list) -> Work:
     """One prefill call over prompts of ``lens`` real tokens each."""
-    n = float(sum(lens))
-    seqs = len(lens)
-    head = m.d * m.vocab
-    pairs = float(sum(x * (x + 1) / 2 for x in lens))
-    flops = (2.0 * (m.matmul_params - head) * n + 2.0 * head * seqs
-             + _attn_flops(m, pairs))
-    byts = (_weight_bytes(m) + BF16 * n * m.d + n * m.kv_bytes_per_token
-            + F32 * seqs * m.vocab)
-    return Work(flops, byts)
+    return arch.of(m).prefill(m, lens)
