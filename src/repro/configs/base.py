@@ -9,7 +9,7 @@ from __future__ import annotations
 import dataclasses
 import importlib
 from dataclasses import dataclass, field
-from typing import Optional, Tuple
+from typing import ClassVar, Optional, Tuple
 
 # ---------------------------------------------------------------------------
 # Input-shape cells (assigned shapes; seq_len x global_batch)
@@ -101,9 +101,43 @@ class ModelConfig:
     param_count_hint: float = 0.0  # published N (for 6ND model-flops)
     source: str = ""
 
+    # --- mechanisms of ``LatentMoEConfig`` (below), fixed off here ---
+    n_dense_layers: ClassVar[int] = 0
+    dense_d_ff: ClassVar[int] = 0
+    n_router_experts: ClassVar[int] = 0
+    expert_offset: ClassVar[int] = 0
+    norm_topk_prob: ClassVar[bool] = True
+    routed_scaling_factor: ClassVar[float] = 1.0
+    kv_lora_rank: ClassVar[int] = 0
+    qk_nope_head_dim: ClassVar[int] = 0
+    qk_rope_head_dim: ClassVar[int] = 0
+    v_head_dim: ClassVar[int] = 0
+    rope_scaling: ClassVar[Tuple[Tuple[str, float], ...]] = ()
+
     @property
     def head_dim(self) -> int:
         return self.d_head or (self.d_model // self.n_heads)
+
+    @property
+    def mla(self) -> bool:
+        """Multi-head latent attention: the cache holds one latent row per
+        token and layer (``latent_width`` wide) in place of K and V."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """The latent row as stored: [c_kv, k_pe] and zero lanes up to a
+        multiple of 128 (576 -> 640), the lanes a TPU tile holds anyway;
+        at 576 the device's default layout of the pool is not row-major
+        and every decode step would copy the whole pool into it."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+    @property
+    def expert_share(self) -> bool:
+        """The expert layers hold ``n_experts`` of the router's
+        ``n_router_experts`` (from ``expert_offset`` on), route over all of
+        them, and compute their own experts' part, dropless."""
+        return self.n_router_experts > 0
 
     @property
     def is_attention_free(self) -> bool:
@@ -118,6 +152,26 @@ class ModelConfig:
 
     def replace(self, **kw) -> "ModelConfig":
         return dataclasses.replace(self, **kw)
+
+
+@dataclass(frozen=True)
+class LatentMoEConfig(ModelConfig):
+    """A decoder with the DeepSeek-V2 block: leading dense layers, then
+    expert layers that may hold a share of the router's experts, and
+    multi-head latent attention with YaRN rotary frequencies.  ``d_ff`` is
+    the expert width; the dense layers have ``dense_d_ff``."""
+    n_dense_layers: int = 0
+    dense_d_ff: int = 0
+    n_router_experts: int = 0
+    expert_offset: int = 0
+    norm_topk_prob: bool = True
+    routed_scaling_factor: float = 1.0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN: ((key, value), ...) of the published ``rope_scaling``
+    rope_scaling: Tuple[Tuple[str, float], ...] = ()
 
 
 # ---------------------------------------------------------------------------
@@ -135,6 +189,7 @@ ARCH_IDS = [
     "dbrx_132b",
     "mamba2_130m",
     "whisper_base",
+    "deepseek_v2_lite",
 ]
 
 CNN_IDS = ["vgg16", "googlenet", "resnet50"]
@@ -150,6 +205,7 @@ _ALIASES = {
     "dbrx-132b": "dbrx_132b",
     "mamba2-130m": "mamba2_130m",
     "whisper-base": "whisper_base",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

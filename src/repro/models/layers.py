@@ -18,6 +18,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from .pspec import pbatch, pkv, pmodel
@@ -79,6 +80,49 @@ def apply_rope(x, positions, theta: float):
     return out.astype(x.dtype)
 
 
+def yarn_get_mscale(scale: float, mscale: float = 1.0) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: dict) -> np.ndarray:
+    """YaRN's (dim/2,) rotary frequencies: the original frequencies on the
+    dimensions that turn fast over the original context
+    (``original_max_position_embeddings``), the frequencies divided by
+    ``factor`` on the slow ones, and a linear ramp between them (``beta_fast``
+    and ``beta_slow`` rotations bound it)."""
+    factor = float(scaling["factor"])
+    orig = float(scaling["original_max_position_embeddings"])
+    pos = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** pos
+    inter = 1.0 / (factor * theta ** pos)
+
+    def corr(rotations):
+        return (dim * math.log(orig / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    lo = max(math.floor(corr(float(scaling.get("beta_fast", 32)))), 0)
+    hi = min(math.ceil(corr(float(scaling.get("beta_slow", 1)))), dim - 1)
+    if lo == hi:
+        hi += 0.001
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return inter * (1.0 - keep) + extra * keep
+
+
+def apply_rope_pairs(x, positions, inv_freq, mscale: float = 1.0):
+    """Rotary embedding of interleaved pairs ``(x[2i], x[2i+1])`` (the
+    DeepSeek-V2 layout), with the frequencies ``inv_freq`` (D/2,) and cos
+    and sin scaled by ``mscale``.  x: (..., S, H, D); positions: (..., S)."""
+    ang = positions[..., None].astype(jnp.float32) * jnp.asarray(
+        inv_freq, jnp.float32)
+    cos = (jnp.cos(ang) * mscale)[..., None, :]              # (..., S, 1, D/2)
+    sin = (jnp.sin(ang) * mscale)[..., None, :]
+    xp = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+    x0, x1 = xp[..., 0], xp[..., 1]
+    out = jnp.stack([x0 * cos - x1 * sin, x0 * sin + x1 * cos], axis=-1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
 # ---------------------------------------------------------------------------
 # chunked online-softmax attention ("flash" in plain XLA)
 # ---------------------------------------------------------------------------
@@ -112,21 +156,24 @@ def flash_attention(
     q_chunk: int = 512,
     kv_chunk: int = 1024,
     logits_soft_cap: float = 0.0,
+    scale: float | None = None,
 ):
     """Chunked attention with online softmax.
 
-    q: (B, Sq, Hq, D);  k, v: (B, Skv, Hkv, D) with Hq % Hkv == 0 (GQA).
-    ``window`` > 0 restricts each query to the last ``window`` keys (SWA).
-    ``q_offset`` is the absolute position of q[0] (for chunked prefill).
-    Returns (B, Sq, Hq, D).
+    q: (B, Sq, Hq, D);  k: (B, Skv, Hkv, D), v: (B, Skv, Hkv, Dv) with
+    Hq % Hkv == 0 (GQA).  ``window`` > 0 restricts each query to the last
+    ``window`` keys (SWA).  ``q_offset`` is the absolute position of q[0]
+    (for chunked prefill).  ``scale`` defaults to 1/sqrt(D).
+    Returns (B, Sq, Hq, Dv).
     """
     B, Sq, Hq, D = q.shape
     _, Skv, Hkv, _ = k.shape
+    Dv = v.shape[-1]
     G = Hq // Hkv
     qc = _chunk_sizes(Sq, q_chunk)
     kc = _chunk_sizes(Skv, kv_chunk)
     nq, nk = Sq // qc, Skv // kc
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
 
     # (nq, B, qc, Hkv, G, D)
     # NOTE: no sharding pins inside the attention loops — constraints here
@@ -135,7 +182,7 @@ def flash_attention(
     # the block-level batch/head sharding correctly on its own.
     qs = q.reshape(B, nq, qc, Hkv, G, D).transpose(1, 0, 2, 3, 4, 5)
     ks = k.reshape(B, nk, kc, Hkv, D).transpose(1, 0, 2, 3, 4)
-    vs = v.reshape(B, nk, kc, Hkv, D).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(B, nk, kc, Hkv, Dv).transpose(1, 0, 2, 3, 4)
 
     q_off = jnp.asarray(q_offset, jnp.int32)
     weff = _window_len(window)
@@ -149,7 +196,7 @@ def flash_attention(
 
         m0 = jnp.full((B, Hkv, G, qc), _NEG, jnp.float32)
         l0 = jnp.zeros((B, Hkv, G, qc), jnp.float32)
-        a0 = jnp.zeros((B, Hkv, G, qc, D), jnp.float32)
+        a0 = jnp.zeros((B, Hkv, G, qc, Dv), jnp.float32)
 
         @jax.checkpoint
         def kv_step(carry, ki_kv):
@@ -187,11 +234,11 @@ def flash_attention(
             (jnp.arange(nk, dtype=jnp.int32), ks, vs),
         )
         out = acc / jnp.maximum(l, 1e-30)[..., None]  # (B,Hkv,G,qc,D)
-        out = out.transpose(0, 3, 1, 2, 4).reshape(B, qc, Hq, D)
+        out = out.transpose(0, 3, 1, 2, 4).reshape(B, qc, Hq, Dv)
         return None, out.astype(q.dtype)
 
     _, outs = lax.scan(q_step, None, (jnp.arange(nq, dtype=jnp.int32), qs))
-    return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, Hq, D)
+    return outs.transpose(1, 0, 2, 3, 4).reshape(B, Sq, Hq, Dv)
 
 
 def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0):
@@ -217,21 +264,23 @@ def naive_attention(q, k, v, *, causal=True, window=None, q_offset=0):
     return o.reshape(B, Sq, Hq, D).astype(q.dtype)
 
 
-def decode_attention(q, k_cache, v_cache, cur_pos, *, window=None):
+def decode_attention(q, k_cache, v_cache, cur_pos, *, window=None,
+                     scale=None):
     """Single-token attention against a (possibly longer) cache.
 
     q: (B, 1, Hq, D); caches: (B, S, Hkv, D); cur_pos: () or (B,) int32 —
     0-indexed position of each slot's current token (cache entries
     [0, cur_pos[b]] are valid; a vector gives every slot its own context
     length, the masked-attention half of per-slot continuous batching).
-    The cache is read as S blocks of one position each.
+    The cache is read as S blocks of one position each.  ``v_cache`` may
+    be ``(B, S, Hkv, Dv)``, and ``scale`` replaces 1/sqrt(D).
     """
     return block_attention(q, k_cache[:, :, :, None], v_cache[:, :, :, None],
-                           cur_pos, window=window)
+                           cur_pos, window=window, scale=scale)
 
 
 def block_attention(q, k_blocks, v_blocks, cur_pos, *, window=None,
-                    block_keep=None):
+                    block_keep=None, scale=None):
     """Single-token attention over gathered head-major KV blocks, read in
     the order they are stored, without flattening the blocks into one
     sequence axis.
@@ -240,14 +289,16 @@ def block_attention(q, k_blocks, v_blocks, cur_pos, *, window=None,
     positions [t*bs, (t+1)*bs); cur_pos: () or (B,) int32.  The softmax runs
     over all T*bs positions.  ``block_keep`` (optional, (B, Hkv, T) bool)
     masks whole blocks per kv-head on top of the causal/window mask (the
-    blockwise-sparse path).
+    blockwise-sparse path).  Value rows may be narrower than key rows
+    (``v_blocks`` (B, T, Hkv, bs, Dv)); ``scale`` replaces 1/sqrt(D).
     """
     B, _, Hq, D = q.shape
     _, T, Hkv, bs, _ = k_blocks.shape
     G = Hq // Hkv
     qr = q.reshape(B, Hkv, G, D)
     s = jnp.einsum("bhgd,bthsd->bhgts", qr, k_blocks,
-                   preferred_element_type=jnp.float32) / math.sqrt(D)
+                   preferred_element_type=jnp.float32)
+    s = s / math.sqrt(D) if scale is None else s * scale
     pos = jnp.arange(T * bs, dtype=jnp.int32).reshape(T, bs)
     cur = jnp.asarray(cur_pos, jnp.int32).reshape(-1, 1, 1)   # (B|1, 1, 1)
     ok = pos[None] <= cur                                     # (B, T, bs)
@@ -261,7 +312,7 @@ def block_attention(q, k_blocks, v_blocks, cur_pos, *, window=None,
     p = jax.nn.softmax(s, axis=(-2, -1))
     o = jnp.einsum("bhgts,bthsd->bhgd", p.astype(v_blocks.dtype), v_blocks,
                    preferred_element_type=jnp.float32)
-    return o.reshape(B, 1, Hq, D).astype(q.dtype)
+    return o.reshape(B, 1, Hq, v_blocks.shape[-1]).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -369,7 +420,7 @@ def attention_decode_slice(p, cfg, x, cache, cur_pos, *, window=None):
 
 def attention_decode_paged(p, q, k_pages, v_pages, layer, tables, cur_pos,
                            *, window=None, k_scales=None, v_scales=None,
-                           sparse_threshold=0.0):
+                           sparse_threshold=0.0, scale=None, w_uv=None):
     """Decode attention of the new token against layer ``layer`` of the
     paged pool.
 
@@ -389,13 +440,25 @@ def attention_decode_paged(p, q, k_pages, v_pages, layer, tables, cur_pos,
     positive ``sparse_threshold`` (static) drops whole KV blocks whose
     estimated attention mass falls below it — selection comes from the
     kernel oracle's ``block_keep_mask`` so model path and kernel agree.
+
+    A latent pool (multi-head latent attention, absorbed form) holds rows
+    (L, N, bs, W) that are read as one KV "head" shared by every query
+    head: ``v_pages`` is None, the values are the first ``w_uv.shape[0]``
+    lanes of each gathered row, and the attention output is mapped back to
+    each head's values by ``w_uv`` (R, Hq, Dv) before ``wo``; ``scale``
+    replaces 1/sqrt(D).
     """
     B = q.shape[0]
     cur = jnp.asarray(cur_pos, jnp.int32)
     # the named scopes let a device trace's per-operation times be read
     # back by part (they change HLO metadata only, not the program)
     with jax.named_scope("kv_gather"):
-        kg, vg = k_pages[layer, tables], v_pages[layer, tables]
+        kg = k_pages[layer, tables]
+        if v_pages is None:
+            kg = kg[:, :, None]                      # (B, T, 1, bs, W)
+            vg = kg[..., :w_uv.shape[0]]
+        else:
+            vg = v_pages[layer, tables]
         if k_scales is not None:
             kg = (kg.astype(jnp.float32)
                   * k_scales[layer, tables][..., None, None]).astype(q.dtype)
@@ -409,9 +472,128 @@ def attention_decode_paged(p, q, k_pages, v_pages, layer, tables, cur_pos,
                 q[:, 0], k_pages[layer], tables, cur,
                 threshold=sparse_threshold, window=window,
                 k_scales=None if k_scales is None else k_scales[layer])
-        o = block_attention(q, kg, vg, cur, window=window, block_keep=keep)
+        o = block_attention(q, kg, vg, cur, window=window, block_keep=keep,
+                            scale=scale)
     with jax.named_scope("attn_proj"):
+        if w_uv is not None:
+            o = mla_values(o, w_uv)
         return o.reshape(B, 1, -1) @ p["wo"]
+
+
+# ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2)
+# ---------------------------------------------------------------------------
+#
+# Per token: q = x @ wq split per head into q_nope and q_pe (no q LoRA);
+# [c, k_pe] = x @ wkv_a, c normed by kv_norm; [k_nope, v] = c @ wkv_b per
+# head; q_pe and k_pe (one for all heads) rotate by interleaved pairs at
+# YaRN frequencies.  The cache holds the latent row [c, k_pe] only.
+# Prefill attends in the decompressed form (per-head k = [k_nope, k_pe]);
+# decode in the absorbed form, where wkv_b's key half is folded into the
+# query (q_nope @ W_UK^T) and its value half applied after the attention
+# (W_UV), so that the cached rows are read as they are.
+
+
+def init_mla(key, cfg, dtype):
+    d, H = cfg.d_model, cfg.n_heads
+    nope, rope, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, \
+        cfg.v_head_dim
+    R = cfg.kv_lora_rank
+    ks = jax.random.split(key, 4)
+    return {
+        "wq": dense_init(ks[0], d, H * (nope + rope), dtype),
+        "wkv_a": dense_init(ks[1], d, R + rope, dtype),
+        "kv_norm": jnp.ones((R,), dtype),
+        "wkv_b": dense_init(ks[2], R, H * (nope + dv), dtype),
+        "wo": dense_init(ks[3], H * dv, d, dtype),
+    }
+
+
+def mla_rope(cfg):
+    """(inv_freq, cos/sin scale) of the rotary half of each head: YaRN's
+    (``cfg.rope_scaling``)."""
+    sc = dict(cfg.rope_scaling)
+    f = float(sc["factor"])
+    return (yarn_inv_freq(cfg.qk_rope_head_dim, cfg.rope_theta, sc),
+            yarn_get_mscale(f, sc.get("mscale", 1.0))
+            / yarn_get_mscale(f, sc.get("mscale_all_dim", 1.0)))
+
+
+def mla_softmax_scale(cfg) -> float:
+    """1/sqrt(qk head size), times YaRN's mscale squared as DeepSeek's own
+    modeling code has it (the transformers port leaves the square out)."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    sc = dict(cfg.rope_scaling)
+    if sc.get("mscale_all_dim"):
+        scale *= yarn_get_mscale(float(sc["factor"]),
+                                 float(sc["mscale_all_dim"])) ** 2
+    return scale
+
+
+def mla_project(p, cfg, x, positions):
+    """q_nope (B, S, H, nope), rotated q_pe (B, S, H, rope) and the latent
+    rows (B, S, ``cfg.latent_width``): normed c, the rotated k_pe, then
+    zero lanes."""
+    B, S, _ = x.shape
+    H, nope, R = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    inv, msc = mla_rope(cfg)
+    q = (x @ p["wq"]).reshape(B, S, H, -1)
+    q_pe = apply_rope_pairs(q[..., nope:], positions, inv, msc)
+    kv = x @ p["wkv_a"]
+    c = rms_norm(kv[..., :R], p["kv_norm"], cfg.norm_eps)
+    k_pe = apply_rope_pairs(kv[:, :, None, R:], positions, inv, msc)
+    return q[..., :nope], q_pe, _lanes(jnp.concatenate([c, k_pe[:, :, 0]],
+                                                      -1), cfg)
+
+
+def _lanes(x, cfg):
+    """``x`` (..., R + rope) with zero lanes up to ``cfg.latent_width``."""
+    pad = cfg.latent_width - x.shape[-1]
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)]) if pad else x
+
+
+def mla_block(p, cfg, x, *, positions=None):
+    """Full-sequence (train / prefill) latent attention in the decompressed
+    form.  Returns (out, latent rows (B, S, ``cfg.latent_width``))."""
+    if positions is None:
+        positions = jnp.arange(x.shape[1], dtype=jnp.int32)[None, :]
+    B, S, _ = x.shape
+    H, nope, R = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("attn_proj"):
+        q_nope, q_pe, rows = mla_project(p, cfg, x, positions)
+        kv = (rows[..., :R] @ p["wkv_b"]).reshape(B, S, H, -1)
+        k_pe = jnp.broadcast_to(
+            rows[:, :, None, R:R + cfg.qk_rope_head_dim],
+            (B, S, H, cfg.qk_rope_head_dim))
+        q = jnp.concatenate([q_nope, q_pe], -1)
+        k = jnp.concatenate([kv[..., :nope], k_pe], -1)
+    with jax.named_scope("attention"):
+        o = flash_attention(q, k, kv[..., nope:], causal=True,
+                            q_chunk=cfg.attn_q_chunk,
+                            kv_chunk=cfg.attn_kv_chunk,
+                            scale=mla_softmax_scale(cfg))
+    with jax.named_scope("attn_proj"):
+        return o.reshape(B, S, -1) @ p["wo"], rows
+
+
+def mla_absorb(p, cfg, x, positions):
+    """The absorbed decode query and the new latent rows, each
+    ``cfg.latent_width`` wide ((B, S, H, W), (B, S, W)), and W_UV
+    (R, H, Dv)."""
+    H, nope, R = cfg.n_heads, cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    with jax.named_scope("mla_absorb"):
+        q_nope, q_pe, rows = mla_project(p, cfg, x, positions)
+        w = p["wkv_b"].reshape(R, H, -1)
+        q_lat = jnp.einsum("bshn,rhn->bshr", q_nope, w[..., :nope])
+        return (_lanes(jnp.concatenate([q_lat, q_pe], -1), cfg), rows,
+                w[..., nope:])
+
+
+def mla_values(o_lat, w_uv):
+    """Each head's attention over latent rows (B, S, H, R) -> its values
+    (B, S, H, Dv)."""
+    with jax.named_scope("mla_absorb"):
+        return jnp.einsum("bshr,rhv->bshv", o_lat, w_uv)
 
 
 # ---------------------------------------------------------------------------

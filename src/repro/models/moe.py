@@ -11,6 +11,13 @@ GSPMD lowers it to all-to-all / all-gather rather than replicated scatter.
   out (T, d) = sum_k gate * buffer_out[inv_slot]        # gather + weighted sum
 
 Auxiliary load-balance loss follows Switch/GShard: E * sum_e f_e * p_e.
+
+An expert share (``cfg.expert_share``, ``moe_share``) is the layer of one
+chip under expert parallelism: it holds ``n_experts`` of the router's
+``n_router_experts`` (from ``expert_offset`` on), routes every token over
+all of them with the published router, and computes the held experts'
+part of the result, dropless: each (token, held expert) pair is computed,
+however many tokens reach one expert.
 """
 from __future__ import annotations
 
@@ -31,7 +38,10 @@ def init_moe(key, cfg, dtype):
     d, f, e = cfg.d_model, cfg.d_ff, cfg.n_experts
     ks = jax.random.split(key, 5)
     p = {
-        "router": dense_init(ks[0], d, e, jnp.float32),
+        # a share's router keeps its published width, in the served dtype
+        "router": (dense_init(ks[0], d, cfg.n_router_experts, dtype)
+                   if cfg.expert_share else dense_init(ks[0], d, e,
+                                                       jnp.float32)),
         "w1": dense_init(ks[1], d, f, dtype).reshape(1, d, f).repeat(e, 0),
         "w2": dense_init(ks[2], f, d, dtype).reshape(1, f, d).repeat(e, 0),
         "w3": dense_init(ks[3], d, f, dtype).reshape(1, d, f).repeat(e, 0),
@@ -134,3 +144,83 @@ def _moe_group(p, cfg, x):
     aux = E * jnp.sum(me * fe)
 
     return out.reshape(B, S, d), aux
+
+
+# ---------------------------------------------------------------------------
+# expert share, dropless
+# ---------------------------------------------------------------------------
+
+
+def route(p, cfg, x):
+    """Router over all ``n_router_experts`` in float32 (the matmul at
+    ``HIGHEST``: a TPU's default precision for float32 operands rounds them
+    to bfloat16, which flips near-tied choices): softmax, greedy top-k,
+    the weights renormalised only under ``norm_topk_prob``, times
+    ``routed_scaling_factor``.  x: (T, d).  Returns the weights (T, K) f32
+    and each choice's held-expert index (T, K), ``n_experts`` where the
+    chosen expert is not held here."""
+    with jax.named_scope("moe_router"):
+        logits = jnp.matmul(x.astype(jnp.float32),
+                            p["router"].astype(jnp.float32),
+                            precision=lax.Precision.HIGHEST)
+        w, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), cfg.top_k)
+        if cfg.norm_topk_prob:
+            w = w / jnp.maximum(w.sum(-1, keepdims=True), 1e-9)
+        w = w * cfg.routed_scaling_factor
+        local = idx - cfg.expert_offset
+        held = (local >= 0) & (local < cfg.n_experts)
+        return w, jnp.where(held, local, cfg.n_experts).astype(jnp.int32)
+
+
+def _held_experts(p, cfg, x, w, eid):
+    """The held experts' weighted sum for one group of tokens: the (token,
+    choice) pairs sorted by expert, one grouped matmul per projection over
+    the held experts' rows (``lax.ragged_dot``; pairs of experts held
+    elsewhere sort last and fall outside every group), then back to token
+    order.  x: (T, d); w, eid: (T, K)."""
+    T, K = eid.shape
+    E = cfg.n_experts
+    with jax.named_scope("moe_experts"):
+        flat = eid.reshape(-1)
+        order = jnp.argsort(flat, stable=True)
+        sizes = jnp.bincount(flat, length=E + 1)[:E].astype(jnp.int32)
+        rows = x[order // K]
+        h = jax.nn.silu(lax.ragged_dot(rows, p["w1"], sizes)) \
+            * lax.ragged_dot(rows, p["w3"], sizes)
+        y = lax.ragged_dot(h, p["w2"], sizes)
+        back = jnp.zeros((T * K,), jnp.int32).at[order].set(
+            jnp.arange(T * K, dtype=jnp.int32))
+        y = y[back].reshape(T, K, -1).astype(jnp.float32)
+        held = eid < E
+        y = jnp.where(held[..., None], y, 0.0)
+        return jnp.einsum("tkd,tk->td", y, jnp.where(held, w, 0.0))
+
+
+def moe_share(p, cfg, x, group_tokens: int = 4096):
+    """The expert share's layer.  x: (B, S, d) -> (out (B, S, d), the number
+    of held experts the tokens reached (int32 scalar)).  Tokens are taken
+    ``group_tokens`` at a time, which bounds the sorted copies and adds no
+    capacity: every pair of every group is computed."""
+    B, S, d = x.shape
+    T = B * S
+    xf = x.reshape(T, d)
+    w, eid = route(p, cfg, xf)
+    reached = jnp.sum(jnp.bincount(eid.reshape(-1), length=cfg.n_experts + 1)
+                      [:cfg.n_experts] > 0).astype(jnp.int32)
+    G = min(T, group_tokens)
+    n = -(-T // G)
+    if n == 1:
+        out = _held_experts(p, cfg, xf, w, eid)
+    else:
+        pad = n * G - T
+        xg = jnp.pad(xf, ((0, pad), (0, 0))).reshape(n, G, d)
+        wg = jnp.pad(w, ((0, pad), (0, 0))).reshape(n, G, -1)
+        eg = jnp.pad(eid, ((0, pad), (0, 0)),
+                     constant_values=cfg.n_experts).reshape(n, G, -1)
+        out = lax.map(lambda a: _held_experts(p, cfg, *a),
+                      (xg, wg, eg)).reshape(n * G, d)[:T]
+    with jax.named_scope("moe_shared"):
+        if cfg.n_shared_experts:
+            hs = jax.nn.silu(xf @ p["ws1"]) * (xf @ p["ws3"])
+            out = out + (hs @ p["ws2"]).astype(jnp.float32)
+    return out.astype(x.dtype).reshape(B, S, d), reached

@@ -22,6 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from . import layers as L
 from . import moe as MOE
@@ -64,16 +65,22 @@ def _dtype(cfg):
 # ---------------------------------------------------------------------------
 
 
-def init_block(key, cfg):
+def init_block(key, cfg, dense=False):
+    """One layer's parameters; ``dense`` makes one of the leading dense
+    layers (``cfg.n_dense_layers``, a SwiGLU of ``cfg.dense_d_ff``)."""
     dt = _dtype(cfg)
     d = cfg.d_model
     ks = jax.random.split(key, 6)
     p = {"ln1": jnp.ones((d,), dt)}
     if has_attn(cfg):
-        p["attn"] = L.init_attention(ks[0], cfg, dt)
+        p["attn"] = (L.init_mla(ks[0], cfg, dt) if cfg.mla
+                     else L.init_attention(ks[0], cfg, dt))
     if has_ssm(cfg):
         p["ssm"] = SSM.init_ssm(ks[1], cfg, dt)
-    if cfg.n_experts:
+    if dense:
+        p["ln2"] = jnp.ones((d,), dt)
+        p["mlp"] = L.init_mlp(ks[3], d, cfg.dense_d_ff, cfg.act, dt)
+    elif cfg.n_experts:
         p["ln2"] = jnp.ones((d,), dt)
         p["moe"] = MOE.init_moe(ks[2], cfg, dt)
     elif has_mlp(cfg) and cfg.d_ff > 0:
@@ -86,11 +93,15 @@ def init_lm(key, cfg):
     dt = _dtype(cfg)
     ks = jax.random.split(key, 4)
     layer_keys = jax.random.split(ks[0], cfg.n_layers)
+    nd = cfg.n_dense_layers
     params = {
         "embed": L.embed_init(ks[1], cfg.vocab, cfg.d_model, dt),
-        "blocks": jax.vmap(lambda k: init_block(k, cfg))(layer_keys),
+        "blocks": jax.vmap(lambda k: init_block(k, cfg))(layer_keys[nd:]),
         "ln_f": jnp.ones((cfg.d_model,), dt),
     }
+    if nd:
+        params["dense_blocks"] = jax.vmap(
+            lambda k: init_block(k, cfg, dense=True))(layer_keys[:nd])
     if not cfg.tie_embeddings:
         params["lm_head"] = L.dense_init(ks[2], cfg.d_model, cfg.vocab, dt)
     if cfg.n_meta_tokens:
@@ -103,6 +114,38 @@ def count_params(params) -> int:
     return int(sum(np.prod(x.shape) for x in jax.tree.leaves(params)))
 
 
+def moe_layer(p, cfg, h):
+    """An expert layer: (out, aux loss, held experts reached).  An expert
+    share (``cfg.expert_share``) runs dropless and reports what it reached;
+    the other configurations keep the capacity layer (reached None)."""
+    if cfg.expert_share:
+        with jax.named_scope("mlp"):
+            out, reached = MOE.moe_share(p, cfg, h)
+        return out, jnp.zeros((), jnp.float32), reached
+    out, aux = MOE.moe_block(p, cfg, h)
+    return out, aux, None
+
+
+def _run_stacks(params, cfg, body, carry, per_layer):
+    """``lax.scan`` of ``body`` over each run of layers of one kind in turn
+    (the leading dense layers, ``params["dense_blocks"]``, then the rest,
+    ``params["blocks"]``: the only run of most configurations), with
+    ``per_layer`` ((L, ...) arrays, cut to each run) beside the stacked
+    parameters.  Returns (carry, the per-layer outputs over all layers)."""
+    nd = cfg.n_dense_layers
+    runs = ([(params["dense_blocks"], 0, nd)] if nd else []) + \
+        [(params["blocks"], nd, cfg.n_layers)]
+    ys = []
+    for bps, lo, hi in runs:
+        xs = per_layer if len(runs) == 1 else \
+            jax.tree.map(lambda a: a[lo:hi], per_layer)
+        carry, y = lax.scan(body, carry, (bps, *xs))
+        ys.append(y)
+    if len(ys) == 1:
+        return carry, ys[0]
+    return carry, jax.tree.map(lambda *a: jnp.concatenate(a), *ys)
+
+
 # ---------------------------------------------------------------------------
 # block application (full sequence)
 # ---------------------------------------------------------------------------
@@ -113,7 +156,10 @@ def apply_block(bp, cfg, x, window, positions):
     aux = jnp.zeros((), jnp.float32)
     h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
     delta = 0.0
-    if has_attn(cfg):
+    if has_attn(cfg) and cfg.mla:
+        a_out, _ = L.mla_block(bp["attn"], cfg, h, positions=positions)
+        delta = delta + a_out
+    elif has_attn(cfg):
         a_out, _ = L.attention_block(bp["attn"], cfg, h, window=window,
                                      positions=positions)
         delta = delta + a_out
@@ -126,7 +172,7 @@ def apply_block(bp, cfg, x, window, positions):
     x = x + delta
     if "moe" in bp:
         h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-        m_out, aux = MOE.moe_block(bp["moe"], cfg, h)
+        m_out, aux, _ = moe_layer(bp["moe"], cfg, h)
         x = x + m_out
     elif "mlp" in bp:
         h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -181,8 +227,8 @@ def forward_hidden(params, cfg, batch):
         return (presidual(x), aux + a), None
 
     body = _remat(cfg, body)
-    (x, aux), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                           (params["blocks"], windows))
+    (x, aux), _ = _run_stacks(params, cfg, body,
+                              (x, jnp.zeros((), jnp.float32)), (windows,))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     if n_prefix:
         x = x[:, n_prefix:]
@@ -245,11 +291,15 @@ def init_cache(cfg, batch, max_len, dtype=None):
 
     ``len`` is a per-slot (B,) vector: every sequence in the batch carries
     its own context length, so ragged prompts and per-slot refill share one
-    cache (a scalar is still accepted by ``decode_step`` for compat)."""
+    cache (a scalar is still accepted by ``decode_step`` for compat).  A
+    latent-attention configuration caches one latent row per position and
+    layer under ``k``, ``(L, B, max_len, latent_width)``, and no ``v``."""
     dt = dtype or _dtype(cfg)
     Lc = cfg.n_layers
     c = {"len": jnp.zeros((batch,), jnp.int32)}
-    if has_attn(cfg):
+    if has_attn(cfg) and cfg.mla:
+        c["k"] = jnp.zeros((Lc, batch, max_len, cfg.latent_width), dt)
+    elif has_attn(cfg):
         hd = cfg.head_dim
         c["k"] = jnp.zeros((Lc, batch, max_len, cfg.n_kv_heads, hd), dt)
         c["v"] = jnp.zeros((Lc, batch, max_len, cfg.n_kv_heads, hd), dt)
@@ -282,7 +332,18 @@ def decode_step(params, cfg, token, cache):
         h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
         delta = 0.0
         new_st, new_cv = st, cv
-        if has_attn(cfg):
+        if has_attn(cfg) and cfg.mla:
+            # absorbed latent attention over the cached latent rows
+            q, row, w_uv = L.mla_absorb(bp["attn"], cfg, h, pos[:, None])
+            b_idx = jnp.arange(B)
+            kc_all = kc_all.at[li, b_idx, pos].set(row[:, 0])
+            kc = lax.dynamic_index_in_dim(kc_all, li, 0,
+                                          keepdims=False)[:, :, None]
+            o = L.decode_attention(q, kc, kc[..., :cfg.kv_lora_rank], pos,
+                                   scale=L.mla_softmax_scale(cfg))
+            delta = delta + L.mla_values(o, w_uv).reshape(B, 1, -1) \
+                @ bp["attn"]["wo"]
+        elif has_attn(cfg):
             kc = lax.dynamic_index_in_dim(kc_all, li, 0, keepdims=False)
             vc = lax.dynamic_index_in_dim(vc_all, li, 0, keepdims=False)
             a_out, kv = L.attention_decode_slice(
@@ -306,7 +367,7 @@ def decode_step(params, cfg, token, cache):
         x = x + delta
         if "moe" in bp:
             h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-            m_out, _ = MOE.moe_block(bp["moe"], cfg, h)
+            m_out, _, _ = moe_layer(bp["moe"], cfg, h)
             x = x + m_out
         elif "mlp" in bp:
             h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
@@ -322,8 +383,8 @@ def decode_step(params, cfg, token, cache):
     cv = cache.get("ssm_conv", dummy)
     lidx = jnp.arange(Lc, dtype=jnp.int32)
 
-    (x, nk, nv), (nst, ncv) = lax.scan(
-        body, (x, kc, vc), (params["blocks"], windows, lidx, st, cv))
+    (x, nk, nv), (nst, ncv) = _run_stacks(
+        params, cfg, body, (x, kc, vc), (windows, lidx, st, cv))
 
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -331,7 +392,9 @@ def decode_step(params, cfg, token, cache):
 
     new_cache = dict(cache)
     if has_attn(cfg):
-        new_cache["k"], new_cache["v"] = nk, nv
+        new_cache["k"] = nk
+        if "v" in cache:
+            new_cache["v"] = nv
     if has_ssm(cfg):
         new_cache["ssm_state"], new_cache["ssm_conv"] = nst, ncv
     new_cache["len"] = cache["len"] + 1
@@ -342,9 +405,13 @@ def _append_block(pages, scales, li, blk, at_row, new, kv_name):
     """Put each slot's new row into its current block and write the whole
     block back: ``pages`` (L, N, Hkv, bs, D) at layer ``li``, ``blk`` (B,)
     block ids, ``at_row`` (B, 1, bs, 1) marking each slot's row, ``new``
-    (B, 1, Hkv, D).  A quantized pool (``scales`` (L, N, Hkv) given)
+    (B, 1, Hkv, D).  A latent pool has ``pages`` (L, N, bs, W) and ``new``
+    (B, 1, W).  A quantized pool (``scales`` (L, N, Hkv) given)
     requantizes the block with a fresh scale.  Returns (pages, scales)."""
     cur = pages[li, blk]                                  # (B, Hkv, bs, D)
+    if pages.ndim == 4:
+        return pages.at[li, blk].set(
+            jnp.where(at_row[:, 0], new.astype(pages.dtype), cur)), None
     row = new[:, 0, :, None, :]                           # (B, Hkv, 1, D)
     if scales is None:
         return pages.at[li, blk].set(
@@ -363,6 +430,9 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
 
     token: (B, 1) int32.  pcache:
       k_pages/v_pages : (L, N, Hkv, bs, D) shared pool of head-major blocks
+                        (a latent-attention configuration: k_pages
+                        (L, N, bs, latent_width) of latent rows, no
+                        v_pages)
       tables          : (B, T) int32 per-slot block chains (null-padded)
       lens            : (B,) int32 per-slot write positions
       k_scales/v_scales (quantized pools): (L, N, Hkv) f32 per-(block,
@@ -379,7 +449,9 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
     ``sparse_threshold`` (static) makes attention skip low-mass KV blocks.
     Returns (logits (B, 1, V) f32, new pcache) with every ``lens`` advanced
     by one (the engine overrides lengths for inactive slots from host
-    bookkeeping).
+    bookkeeping).  An expert share (``cfg.expert_share``) also returns
+    ``experts_reached`` in the new pcache: (L,) int32, the held experts
+    the step's tokens reached in each layer (0 in a dense layer).
     """
     x = pbatch(params["embed"][token])  # (B,1,d)
     pos = jnp.asarray(pcache["lens"], jnp.int32)
@@ -390,12 +462,13 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
     if quant:
         kv_name = "int8" if pcache["k_pages"].dtype == jnp.int8 else "fp8"
     if has_attn(cfg):
-        bs = pcache["k_pages"].shape[3]
+        bs = pcache["k_pages"].shape[2 if cfg.mla else 3]
         # each slot's current block, and its row there
         blk = jnp.take_along_axis(
             tables, jnp.clip(pos // bs, 0, tables.shape[1] - 1)[:, None],
             axis=1)[:, 0]
         at_row = (jnp.arange(bs) == (pos % bs)[:, None])[:, None, :, None]
+    scale = L.mla_softmax_scale(cfg) if cfg.mla else None
 
     def body(carry, xs):
         x, kp_all, vp_all, ks_all, vs_all = carry
@@ -405,18 +478,24 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
         delta = 0.0
         new_st, new_cv = st, cv
         if has_attn(cfg):
+            w_uv = None
             with jax.named_scope("attn_proj"):
-                q, k_new, v_new = L.attention_qkv(bp["attn"], cfg, h,
+                if cfg.mla:
+                    q, k_new, w_uv = L.mla_absorb(bp["attn"], cfg, h,
                                                   pos[:, None])
+                else:
+                    q, k_new, v_new = L.attention_qkv(bp["attn"], cfg, h,
+                                                      pos[:, None])
             with jax.named_scope("kv_append"):
                 kp_all, ks_all = _append_block(kp_all, ks_all, li, blk,
                                                at_row, k_new, kv_name)
-                vp_all, vs_all = _append_block(vp_all, vs_all, li, blk,
-                                               at_row, v_new, kv_name)
+                if vp_all is not None:
+                    vp_all, vs_all = _append_block(vp_all, vs_all, li, blk,
+                                                   at_row, v_new, kv_name)
             delta = delta + L.attention_decode_paged(
                 bp["attn"], q, kp_all, vp_all, li, tables, pos, window=win,
                 k_scales=ks_all, v_scales=vs_all,
-                sparse_threshold=sparse_threshold)
+                sparse_threshold=sparse_threshold, scale=scale, w_uv=w_uv)
         if has_ssm(cfg):
             s_out, sc = SSM.ssm_decode(bp["ssm"], cfg, h,
                                        {"state": st, "conv": cv})
@@ -426,15 +505,20 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
             else:
                 delta = delta + s_out
         x = x + delta
+        reached = None
         if "moe" in bp:
-            h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-            m_out, _ = MOE.moe_block(bp["moe"], cfg, h)
+            with jax.named_scope("mlp"):
+                h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
+            m_out, _, reached = moe_layer(bp["moe"], cfg, h)
             x = x + m_out
         elif "mlp" in bp:
             with jax.named_scope("mlp"):
                 h = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
             x = x + L.mlp_block(bp["mlp"], h, cfg.act)
-        return (x, kp_all, vp_all, ks_all, vs_all), (new_st, new_cv)
+        ys = (new_st, new_cv)
+        if cfg.expert_share:
+            ys += (jnp.zeros((), jnp.int32) if reached is None else reached,)
+        return (x, kp_all, vp_all, ks_all, vs_all), ys
 
     Lc = cfg.n_layers
     dummy = jnp.zeros((Lc, 0), _dtype(cfg))
@@ -446,8 +530,8 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
     cv = pcache.get("ssm_conv", dummy)
     lidx = jnp.arange(Lc, dtype=jnp.int32)
 
-    (x, nkp, nvp, nks, nvs), (nst, ncv) = lax.scan(
-        body, (x, kp, vp, ks, vs), (params["blocks"], windows, lidx, st, cv))
+    (x, nkp, nvp, nks, nvs), ys = _run_stacks(
+        params, cfg, body, (x, kp, vp, ks, vs), (windows, lidx, st, cv))
 
     with jax.named_scope("lm_head"):
         x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
@@ -456,11 +540,15 @@ def decode_step_paged(params, cfg, token, pcache, *, sparse_threshold=0.0):
 
     new_pcache = dict(pcache)
     if has_attn(cfg):
-        new_pcache["k_pages"], new_pcache["v_pages"] = nkp, nvp
+        new_pcache["k_pages"] = nkp
+        if vp is not None:
+            new_pcache["v_pages"] = nvp
         if quant:
             new_pcache["k_scales"], new_pcache["v_scales"] = nks, nvs
     if has_ssm(cfg):
-        new_pcache["ssm_state"], new_pcache["ssm_conv"] = nst, ncv
+        new_pcache["ssm_state"], new_pcache["ssm_conv"] = ys[0], ys[1]
+    if cfg.expert_share:
+        new_pcache["experts_reached"] = ys[2]
     new_pcache["lens"] = pos + 1
     return logits, new_pcache
 
@@ -473,7 +561,10 @@ def prefill(params, cfg, batch, max_len=None, lens=None):
     "last-position" logits at their own final token (causal masking makes
     the pad tokens after a slot's length invisible to it), and the cache
     ``len`` vector records each slot's true context.  Without ``lens`` every
-    slot uses the full width.
+    slot uses the full width.  A latent-attention configuration writes each
+    layer's latent rows into one cache slab carried through the layers
+    (in place), so that its runs of layers build one cache without a
+    second copy of it.
 
     Returns (last-position logits (B, V) f32, cache).
     """
@@ -484,13 +575,17 @@ def prefill(params, cfg, batch, max_len=None, lens=None):
     positions = jnp.arange(S, dtype=jnp.int32)[None, :]
     windows = jnp.asarray(layer_windows(cfg))
 
-    def body(x, xs):
-        bp, win = xs
+    def layer(x, bp, win):
         x = presidual(x)
         h = L.rms_norm(x, bp["ln1"], cfg.norm_eps)
         delta = 0.0
         kv = st = None
-        if has_attn(cfg):
+        outs = {}
+        if has_attn(cfg) and cfg.mla:
+            a_out, outs["k"] = L.mla_block(bp["attn"], cfg, h,
+                                           positions=positions)
+            delta = delta + a_out
+        elif has_attn(cfg):
             a_out, kv = L.attention_block(bp["attn"], cfg, h, window=win,
                                           positions=positions)
             delta = delta + a_out
@@ -499,13 +594,13 @@ def prefill(params, cfg, batch, max_len=None, lens=None):
             delta = (delta + s_out) * 0.5 if has_attn(cfg) else delta + s_out
         x = x + delta
         if "moe" in bp:
-            hh = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
-            m_out, _ = MOE.moe_block(bp["moe"], cfg, hh)
+            with jax.named_scope("mlp"):
+                hh = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
+            m_out, _, _ = moe_layer(bp["moe"], cfg, hh)
             x = x + m_out
         elif "mlp" in bp:
             hh = L.rms_norm(x, bp["ln2"], cfg.norm_eps)
             x = x + L.mlp_block(bp["mlp"], hh, cfg.act)
-        outs = {}
         if kv is not None:
             k, v = kv
             pad = [(0, 0), (0, max_len - S), (0, 0), (0, 0)]
@@ -519,7 +614,31 @@ def prefill(params, cfg, batch, max_len=None, lens=None):
             outs["ssm_conv"] = st["conv"]
         return x, outs
 
-    x, caches = lax.scan(body, x, (params["blocks"], windows))
+    if cfg.mla:
+        def body(carry, xs):
+            x, slab = carry
+            bp, win, li = xs
+            x, outs = layer(x, bp, win)
+            # row-major rows: the slab then keeps the output's layout, where
+            # the layout the projection's weights suggest would make XLA
+            # relayout the whole slab after the last layer
+            rows = with_layout_constraint(outs["k"].astype(slab.dtype),
+                                          Layout(major_to_minor=(0, 1, 2)))
+            slab = lax.dynamic_update_slice(slab, rows[None], (li, 0, 0, 0))
+            return (x, slab), None
+
+        slab = jnp.zeros((cfg.n_layers, B, max_len, cfg.latent_width),
+                         _dtype(cfg))
+        (x, slab), _ = _run_stacks(
+            params, cfg, body, (x, slab),
+            (windows, jnp.arange(cfg.n_layers, dtype=jnp.int32)))
+        caches = {"k": slab}
+    else:
+        def body(x, xs):
+            bp, win = xs
+            return layer(x, bp, win)
+
+        x, caches = _run_stacks(params, cfg, body, x, (windows,))
     x = L.rms_norm(x, params["ln_f"], cfg.norm_eps)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     if lens is None:

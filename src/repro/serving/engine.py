@@ -60,7 +60,7 @@ from repro.profiling.cost_model import (AnalyticCostModel,  # noqa: F401
 from repro.profiling.timer import shape_key
 from repro.serving.kv_pool import (KV_DTYPES, NULL_BLOCK, BlockPool,
                                    ChainAlloc, PoolExhausted,
-                                   write_prefix_pages)
+                                   write_latent_pages, write_prefix_pages)
 from repro.serving.queue import Request
 
 # model families whose per-sequence state does not live (only) in KV blocks:
@@ -142,6 +142,14 @@ class EngineBase:
             raise ValueError("sparse_threshold must be in [0, 1) — it is a "
                              "per-block attention-mass cutoff, and >= 1 "
                              f"would drop every block (got {sparse_threshold})")
+        if cfg.mla and (kv_dtype != "fp32" or sparse_threshold > 0.0
+                        or prefix_cache):
+            raise ValueError(
+                "a latent (MLA) pool is served at the model's dtype, read "
+                "whole and unshared: packed kv_dtype pages, blockwise-sparse "
+                "reads and prefix caching are not implemented for it "
+                f"(kv_dtype={kv_dtype!r}, sparse_threshold="
+                f"{sparse_threshold}, prefix_cache={prefix_cache})")
         if (kv_dtype != "fp32" or sparse_threshold > 0.0) \
                 and cfg.family in _NO_KV_QUANT_FAMILIES:
             raise ValueError(
@@ -198,6 +206,9 @@ class EngineBase:
         self.n_prefix_hits = 0     # seatings that reused cached content
         self.n_cached_tokens = 0   # cache positions served from the index
         self.completed: List[Request] = []
+        # an expert share's last paged decode step: (L,) int32 on the
+        # device, the held experts its tokens reached in each layer
+        self.experts_reached = None
         self._prefix = (getattr(cfg, "n_meta_tokens", 0) or 0) + \
                        (getattr(cfg, "n_img_tokens", 0) or 0)
         # opt-in observability (repro.obs): request lifecycle hops at
@@ -515,6 +526,13 @@ class EngineBase:
                 cost = self.cost_model.decode(ctxs)
             toks = self._run_timed("decode", len(ctxs), sum(ctxs),
                                    self._run_decode)
+            if self.experts_reached is not None and self.tracer is not None \
+                    and self.tracer.wall:
+                # a host copy of (L,) ints, made only under a wall tracer
+                self.tracer.counter(
+                    "host", self.pid, "experts_reached", None,
+                    experts_reached=int(np.asarray(
+                        self.experts_reached).sum()))
             with span(self.tracer, "engine.decode.bookkeeping", self.pid):
                 firsts: List[Request] = []
                 for i, req in enumerate(self.active):
@@ -659,8 +677,12 @@ class EngineBase:
 
 
 def _scatter_prefix(pages, k, v, src, tables):
-    """Scatter batch rows ``src`` of dense K/V ``(L, B, S, Hkv, D)`` into
-    the pool blocks ``tables`` names."""
+    """Scatter batch rows ``src`` of dense K/V ``(L, B, S, Hkv, D)`` (a
+    latent pool: latent rows, ``v`` None) into the pool blocks ``tables``
+    names."""
+    if v is None:
+        return {"k_pages": write_latent_pages(pages["k_pages"], k, tables,
+                                               src)}
     return write_prefix_pages(pages, k[:, src], v[:, src], tables)
 
 
@@ -848,13 +870,11 @@ class PartitionEngine(EngineBase):
                 # divert their rewrite to the null block so a reference-
                 # shared block is never written
                 tables[j, :self.slot_shared[i]] = NULL_BLOCK
-            sub = {"k_pages": self.pages["k_pages"],
-                   "v_pages": self.pages["v_pages"]}
-            if "k_scales" in self.pages:
-                sub["k_scales"] = self.pages["k_scales"]
-                sub["v_scales"] = self.pages["v_scales"]
+            sub = {k: self.pages[k] for k in
+                   ("k_pages", "v_pages", "k_scales", "v_scales")
+                   if k in self.pages}
             self.pages.update(self._write_pages(
-                sub, cache["k"], cache["v"], jnp.asarray(src, jnp.int32),
+                sub, cache["k"], cache.get("v"), jnp.asarray(src, jnp.int32),
                 jnp.asarray(tables)))
         if self._has_ssm():
             rows_a = jnp.asarray(rows, jnp.int32)
@@ -940,7 +960,8 @@ class PartitionEngine(EngineBase):
             if self.pages is not None and "k_pages" in self.pages:
                 tbl = np.asarray(self.slot_tables[i], np.int32)
                 out["k"] = np.asarray(self.pages["k_pages"][:, tbl])
-                out["v"] = np.asarray(self.pages["v_pages"][:, tbl])
+                if "v_pages" in self.pages:
+                    out["v"] = np.asarray(self.pages["v_pages"][:, tbl])
                 if "k_scales" in self.pages:
                     # packed pages travel as-is; ship their scales so the
                     # importer can rebuild the quantized layout exactly
@@ -992,8 +1013,9 @@ class PartitionEngine(EngineBase):
                 kd = self.pages["k_pages"].dtype
                 self.pages["k_pages"] = self.pages["k_pages"].at[:, tbl].set(
                     jnp.asarray(pages["k"]).astype(kd))
-                self.pages["v_pages"] = self.pages["v_pages"].at[:, tbl].set(
-                    jnp.asarray(pages["v"]).astype(kd))
+                if "v" in pages:
+                    self.pages["v_pages"] = self.pages["v_pages"].at[
+                        :, tbl].set(jnp.asarray(pages["v"]).astype(kd))
                 if "k_scales" in pages:
                     sd = self.pages["k_scales"].dtype
                     self.pages["k_scales"] = \
@@ -1045,6 +1067,7 @@ class PartitionEngine(EngineBase):
             elif self.paged:
                 logits, pcache = self._decode_fn(self.params, self._last_tok,
                                                  pcache)
+                self.experts_reached = pcache.pop("experts_reached", None)
                 self.pages = {k: v for k, v in pcache.items()
                               if k not in ("tables", "lens")}
             else:

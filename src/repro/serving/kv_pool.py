@@ -410,11 +410,26 @@ def init_pages(cfg, n_blocks: int, block_size: int, dtype=None, *,
     blocks in the order they are stored.
     With a quantized ``kv_dtype`` the pages are packed (int8/fp8) and the
     dict carries ``k_scales``/``v_scales`` ``(L, n_blocks, Hkv)`` float32 —
-    their presence is how downstream consumers detect the layout."""
+    their presence is how downstream consumers detect the layout.
+
+    A latent-attention configuration (``cfg.mla``) caches one latent row
+    per token and layer and no values: ``k_pages: (L, n_blocks,
+    block_size, latent_width)``, one array with no head axis (attention
+    reads a gathered block as one KV head that every query head shares),
+    and no ``v_pages``; rows are written by ``write_latent_pages``.  A row
+    is [c_kv, k_pe] (576 values for DeepSeek-V2) and zero lanes up to 640
+    (``cfg.latent_width``), what a TPU tile holds of it anyway."""
     import jax.numpy as jnp
 
     if cfg.family == "ssm":
         return {}
+    if cfg.mla:
+        if kv_dtype != "fp32":
+            raise ValueError("a latent pool stores its rows at the model's "
+                             f"dtype; kv_dtype {kv_dtype!r} is not served")
+        return {"k_pages": jnp.zeros(
+            (cfg.n_layers, n_blocks, block_size, cfg.latent_width),
+            dtype or jnp.dtype(cfg.dtype))}
     shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads, block_size, cfg.head_dim)
     if kv_dtype != "fp32":
         qdt, _ = _kv_qspec(kv_dtype)
@@ -425,6 +440,32 @@ def init_pages(cfg, n_blocks: int, block_size: int, dtype=None, *,
                 "v_scales": jnp.zeros(sshape, jnp.float32)}
     dt = dtype or jnp.dtype(cfg.dtype)
     return {"k_pages": jnp.zeros(shape, dt), "v_pages": jnp.zeros(shape, dt)}
+
+
+def write_latent_pages(kp, rows, tables, src):
+    """Scatter batch rows ``src`` of latent rows ``(L, B, S, W)`` into the
+    blocks ``tables`` names of ``kp`` ``(L, N, bs, W)``, one layer at a
+    time, so that no copy of all the rows is made besides the pool (which
+    the loop updates in place)."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    bs = kp.shape[2]
+    T = tables.shape[1]
+    pad = T * bs - rows.shape[2]
+    if pad < 0:
+        raise ValueError(
+            f"prefix of {rows.shape[2]} tokens exceeds the table capacity "
+            f"of {T * bs} (T={T} blocks x block_size={bs}); the pool never "
+            "silently truncates context")
+    idx = jnp.asarray(tables, jnp.int32).reshape(-1)
+
+    def one(layer, kp):
+        r = jnp.pad(rows[layer][src], ((0, 0), (0, pad), (0, 0)))
+        return kp.at[layer, idx].set(
+            r.reshape(-1, bs, r.shape[-1]).astype(kp.dtype))
+
+    return lax.fori_loop(0, rows.shape[0], one, kp)
 
 
 def write_prefix_pages(pages: Dict, k, v, tables) -> Dict:
