@@ -15,14 +15,17 @@ Continuous batching is *per-slot*: every slot carries its own context
 length and block table, so a prefill wave may mix prompt lengths freely,
 and when a slot's request completes mid-wave its blocks return to the pool
 and the next backlog request prefills its OWN prompt into fresh blocks —
-no shared-prefix boundary, no wave-chain cap.  The refill prefill is priced
-and billed into the tick that triggered it, so a refilled request's TTFT
-reflects its own slot prefill rather than the wave boundary.  Refill is
-FIFO and gated only by pool capacity (``PoolExhausted`` is a hard report,
-never a silent truncation).  The dense per-wave layout survives behind
-``paged=False`` — per-slot cache lengths with masked attention give it the
-same ragged/refill semantics inside one ``(L, slots, max_len)`` slab — and
-is the oracle the paged engine is equivalence-tested against.
+no shared-prefix boundary, no wave-chain cap.  A slot that is already
+empty (a partial wave's) is seated the same way at the next op's commit,
+so requests that arrive while the engine is busy join its decode batch.
+The refill prefill is priced and billed into the tick that triggered it,
+so a refilled request's TTFT reflects its own slot prefill rather than
+the wave boundary.  Refill is FIFO and gated only by pool capacity
+(``PoolExhausted`` is a hard report, never a silent truncation).  The
+dense per-wave layout survives behind ``paged=False`` — per-slot cache
+lengths with masked attention give it the same ragged/refill semantics
+inside one ``(L, slots, max_len)`` slab — and is the oracle the paged
+engine is equivalence-tested against.
 
 Phase costs (FLOPs / bytes / duration / bandwidth demand) come from the
 engine's ``CostModel`` (``repro.profiling.cost_model``).  The default
@@ -113,9 +116,15 @@ class EngineBase:
     ``issue_*`` runs the model and mutates slot state eagerly (the next op
     cannot be issued before the previous one commits, so ordering is safe);
     ``commit_op`` applies the time-dependent effects at the clock-chosen
-    end instant and returns any refill-prefill cost triggered by requests
-    that completed in the op.  The one-shot ``prefill_wave``/``decode_step``
-    wrappers preserve the original lockstep semantics exactly.
+    end instant and returns the cost of the refill prefills it ran.  A
+    drained engine admits a wave; a busy one admits at each commit, where
+    the backlog is seated FIFO, one batch-1 refill each, into every free
+    slot: those the op's finished requests freed and those left empty
+    before (a partial wave's), as far as the pool holds them.  So requests
+    assigned while the engine is busy join it at its next commit instead
+    of waiting for it to drain.  ``wave_only`` engines seat only at waves.
+    The one-shot ``prefill_wave``/``decode_step`` wrappers preserve the
+    original lockstep semantics exactly.
 
     Per-slot state: ``slot_lens[i]`` is slot i's context length (cache
     write position, prefix tokens included) and ``slot_tables[i]`` its
@@ -200,6 +209,8 @@ class EngineBase:
         self.slot_tokens: List[List[int]] = [[] for _ in range(slots)]
         self.n_prefills = 0
         self.n_refills = 0
+        # refills into a slot that no request of the same commit freed
+        self.n_free_seats = 0
         self.n_decode_steps = 0
         self.n_exports = 0
         self.n_imports = 0
@@ -243,6 +254,7 @@ class EngineBase:
             ("engine.backlog", float(len(self.backlog))),
             ("engine.decode_steps", float(self.n_decode_steps)),
             ("engine.exports", float(self.n_exports)),
+            ("engine.free_slot_seats", float(self.n_free_seats)),
             ("engine.imports", float(self.n_imports)),
             ("engine.prefills", float(self.n_prefills)),
             ("engine.refills", float(self.n_refills)),
@@ -549,7 +561,8 @@ class EngineBase:
     def commit_op(self, pending: PendingOp,
                   t_end: float) -> Optional[PhaseCost]:
         """Apply the op's time-dependent effects at its end instant: stamp
-        first tokens, retire completed requests, refill freed slots.
+        first tokens, retire completed requests, seat the backlog into
+        every free slot.
         Returns the combined cost of any refill prefills (the caller bills
         them into its tick or schedules them as a follow-on span)."""
         with span(self.tracer, "engine.commit", self.pid):
@@ -583,25 +596,34 @@ class EngineBase:
                                         tokens=len(req.tokens))
 
     def _finish_done(self, t_end: float) -> Optional[PhaseCost]:
-        """Retire finished requests and refill their slots per-slot: the
-        newcomer's OWN prompt is prefilled into freshly allocated blocks
-        (FIFO, gated only by pool capacity).  Returns the combined cost of
-        any refill prefills so the caller can bill them into its tick."""
+        """Retire finished requests, then seat the backlog FIFO into every
+        free slot: the slots this commit freed and those already empty (a
+        partial wave's, or one the pool could not fill at an earlier
+        commit).  Each newcomer's OWN prompt is prefilled into freshly
+        allocated blocks, gated only by pool capacity: a request the pool
+        cannot hold waits at the head, and everything behind it waits too.
+        Seatings into a slot that no request of this commit freed count in
+        ``n_free_seats``.  Returns the combined cost of the refill prefills
+        so the caller can bill them into its tick."""
         extra: Optional[PhaseCost] = None
         t_cursor = t_end
+        refill = self._supports_slot_refill()
         for i, req in enumerate(self.active):
-            if req is None or not req.done:
+            freed = req is not None and req.done
+            if freed:
+                self._retire(i, req, t_end)
+            elif req is not None:
                 continue
-            self._retire(i, req, t_end)
             # chained refill: a newcomer whose prefill-emitted first token
             # already exhausts its budget retires immediately and frees the
             # slot for the next backlog request within the same tick
-            while self.backlog and self._supports_slot_refill():
+            while refill and self.backlog:
                 nxt = self.backlog[0]
                 if (self._ctx_budget(nxt) > self.max_len
                         or not self.pool.can_fit(self._ctx_budget(nxt))):
-                    # exhausted now (retried on the next completion);
-                    # over-budget requests surface as ValueError at the wave
+                    # exhausted now (retried at the next free slot or
+                    # commit); over-budget requests surface as ValueError
+                    # at the wave
                     break
                 with span(self.tracer, "engine.refill", self.pid):
                     self.backlog.pop(0)
@@ -630,6 +652,15 @@ class EngineBase:
                             self.tracer.lifecycle.event(
                                 nxt.rid, "first_token", t_cursor,
                                 pid=self.pid)
+                if not freed:
+                    # a chained newcomer's retire frees the slot in this
+                    # commit, so only the first seating here counts
+                    freed = True
+                    self.n_free_seats += 1
+                    if self.tracer is not None and self.tracer.wall:
+                        self.tracer.counter(
+                            "host", self.pid, "free_slot_seats", None,
+                            free_slot_seats=self.n_free_seats)
                 if not nxt.done:
                     break
                 self._retire(i, nxt, t_cursor)

@@ -117,6 +117,34 @@ def test_event_clock_completes_all_with_refills():
     assert m.virtual_seconds > 0
 
 
+def test_open_arrivals_between_spans_fill_more_than_one_slot():
+    """Requests submitted one per scheduler event, as an open-loop client
+    does, while the engine is busy: each joins the running batch at the
+    next commit, so more than one slot decodes at once, and every request
+    is served in arrival order.  (Seating only into slots a commit frees,
+    the first request's wave of one would stay the only running slot.)"""
+    q = RequestQueue()
+    eng = _fleet(_cfg(), 1, slots=4)[0]
+    sched = make_scheduler([eng], q, policy="uniform", clock="event")
+    rng = np.random.default_rng(3)
+    n, most = 8, 0
+    for _ in range(200):
+        if q.n_submitted < n:
+            q.submit(rng.integers(1, 100, size=(8,)).astype(np.int32), 6)
+        if not (eng.busy or eng.backlog or len(q)):
+            break
+        sched.run(max_spans=1)
+        most = max(most, sum(r is not None for r in eng.active))
+    done = sorted(q.completed, key=lambda r: r.rid)
+    assert len(done) == n and q.n_submitted == n
+    assert all(len(r.tokens) == r.max_new_tokens for r in done)
+    assert most > 1 and eng.n_free_seats > 0
+    assert eng.n_prefills == 1            # it never drained until the end
+    assert eng.assign_order == list(range(n))
+    firsts = [r.t_first_token for r in done]
+    assert firsts == sorted(firsts)
+
+
 def test_event_spans_overlap_across_partitions():
     """The whole point of the event clock: one partition's prefill is in
     flight while another's decode steps run — the per-span trace must show

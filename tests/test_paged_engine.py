@@ -79,6 +79,47 @@ def test_paged_decode_logits_match_dense_oracle(built):
     assert ep.pool.n_live == 0  # every block returned to the pool
 
 
+def test_request_seated_into_a_never_used_slot_decodes_as_alone(built):
+    """A request assigned while the engine decodes another is seated into
+    a slot no request has used (batch-1 refill at the next commit).  It
+    gets exactly the greedy tokens it gets when served alone, and its
+    logits row at each decode step matches the lone run's."""
+    cfg, m, params = built
+    rng = np.random.default_rng(11)
+    p0, p1 = (rng.integers(1, cfg.vocab, size=(n,)).astype(np.int32)
+              for n in (8, 12))
+
+    shared = _engine(cfg, m, params, True)
+    q = RequestQueue()
+    q.submit(p0, 6)
+    shared.assign(q.pop(1))
+    shared.prefill_wave(0.0)
+    assert shared.active[1] is None and shared.slot_tables[1] == []
+    late = q.submit(p1, 4)
+    shared.assign(q.pop(1))
+    shared.decode_step(1.0)              # its commit seats ``late``
+    assert shared.active[1] is late and shared.n_free_seats == 1
+    rows = []
+    while not late.done:
+        assert shared.active[0] is not None   # decoding beside the other
+        shared.decode_step(2.0)
+        rows.append(shared.last_logits[1].copy())
+
+    alone = _engine(cfg, m, params, True)
+    q = RequestQueue()
+    solo = q.submit(p1, 4)
+    alone.assign(q.pop(1))
+    alone.prefill_wave(0.0)
+    lone_rows = []
+    while alone.busy:
+        alone.decode_step(1.0)
+        lone_rows.append(alone.last_logits[0].copy())
+
+    assert late.tokens == solo.tokens and len(rows) == len(lone_rows) == 3
+    for a, b in zip(rows, lone_rows):
+        np.testing.assert_allclose(a, b, rtol=2e-4, atol=2e-4)
+
+
 @pytest.mark.parametrize("kv_dtype", ["fp32", "int8"])
 def test_decode_step_appends_one_row_per_active_slot(kv_dtype):
     """One decode step changes the pool only where it appends: in each

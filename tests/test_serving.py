@@ -194,6 +194,125 @@ def test_refill_ttft_prices_own_prompt_not_wave():
 
 
 # ---------------------------------------------------------------------------
+# admission into free slots: a busy engine seats its backlog into every
+# empty slot at each commit, not only into the slots the commit freed
+# ---------------------------------------------------------------------------
+
+
+def _submit(queue, gens, prompt_lens=None):
+    rng = np.random.default_rng(1)
+    lens = prompt_lens or [8] * len(gens)
+    return [queue.submit(rng.integers(1, 100, size=(n,)).astype(np.int32), g)
+            for n, g in zip(lens, gens)]
+
+
+def _free_seats(eng) -> float:
+    return dict(eng.metrics_snapshot())["engine.free_slot_seats"]
+
+
+def _wave_of_one(q, eng, gen=6):
+    """Seat one request by a wave; the engine's other slots stay empty."""
+    _submit(q, [gen])
+    eng.assign(q.pop(1))
+    eng.prefill_wave(0.0)
+    assert [r is not None for r in eng.active] == \
+        [True] + [False] * (eng.slots - 1)
+
+
+def test_busy_engine_seats_backlog_into_every_free_slot():
+    """A wave of one, then three requests assigned while the engine is
+    busy: the next commit seats all three, FIFO, into the empty slots (a
+    busy engine never drains, so a wave would never seat them)."""
+    q = RequestQueue()
+    eng = _fleet(_cfg(), 1, slots=4)[0]
+    _wave_of_one(q, eng)
+    _submit(q, [6, 6, 6])
+    eng.assign(q.pop(3))
+    assert not eng.wants_prefill
+    eng.decode_step(1.0)
+    assert [r.rid for r in eng.active] == [0, 1, 2, 3]
+    assert eng.assign_order == [0, 1, 2, 3] and eng.backlog == []
+    assert eng.n_refills == 3 and _free_seats(eng) == 3
+    eng.decode_step(2.0)            # all four decode in one step
+    assert [len(r.tokens) for r in eng.active] == [3, 2, 2, 2]
+
+
+def test_free_slot_seating_stops_at_the_first_request_the_pool_cannot_hold():
+    """FIFO with no skipping: a head the pool cannot hold stays queued,
+    and so does the small request behind it, though it would fit."""
+    q = RequestQueue()
+    # blocks of 8: the first request takes 2, the big one needs 6, the
+    # small one 2; 6 usable blocks leave 4 free while the first decodes
+    eng = SimulatedEngine(_cfg(), slots=4, max_len=64, block_size=8,
+                          pool_blocks=7, peak_flops=hw.TPU_PEAK_FLOPS)
+    _wave_of_one(q, eng, gen=8)
+    _submit(q, [8, 4], prompt_lens=[40, 8])
+    big, small = q.pop(2)
+    eng.assign([big, small])
+    eng.decode_step(1.0)
+    assert eng.pool.n_free == 4 and eng.backlog == [big, small]
+    assert eng.active[1:] == [None] * 3 and _free_seats(eng) == 0
+    t = 2.0
+    while eng.busy:
+        eng.decode_step(t)
+        t += 1.0
+    assert len(eng.completed) == 3
+    assert eng.assign_order == [0, big.rid, small.rid]
+    assert eng.pool.n_live == 0
+
+
+def test_newcomer_done_on_its_first_token_frees_the_free_slot_again():
+    """A request seated into an empty slot whose first token ends it
+    retires in the same commit, and the next request takes that slot;
+    only the first seating counts as a free-slot seat."""
+    q = RequestQueue()
+    eng = _fleet(_cfg(), 1, slots=4)[0]
+    _wave_of_one(q, eng)
+    one, nxt = _submit(q, [1, 3])
+    eng.assign(q.pop(2))
+    eng.decode_step(1.0)
+    assert one.done and one in eng.completed and len(one.tokens) == 1
+    assert eng.active[1] is nxt and eng.active[2:] == [None, None]
+    assert eng.n_refills == 2 and _free_seats(eng) == 1
+
+
+def test_wave_only_engine_seats_only_at_waves():
+    q = RequestQueue()
+    eng = SimulatedEngine(_cfg(), slots=4, max_len=64, wave_only=True,
+                          peak_flops=hw.TPU_PEAK_FLOPS)
+    _wave_of_one(q, eng, gen=3)
+    _submit(q, [3, 3, 3])
+    eng.assign(q.pop(3))
+    t = 1.0
+    while eng.busy:
+        eng.decode_step(t)
+        t += 1.0
+        assert eng.active[1:] == [None] * 3 and len(eng.backlog) == 3
+    assert eng.wants_prefill
+    eng.prefill_wave(t)
+    assert [r is not None for r in eng.active] == [True] * 3 + [False]
+    assert eng.n_refills == 0 and _free_seats(eng) == 0
+
+
+@pytest.mark.parametrize("slots, gens, refills", [
+    (2, [4] * 7, 5),
+    (4, [3, 5, 2, 4, 6, 1, 3, 2, 4, 5], 6),
+])
+def test_offline_full_waves_seat_as_before(slots, gens, refills):
+    """An offline backlog fills every slot at the wave, so each refill
+    goes into a slot the same commit freed: FIFO service, one wave, the
+    refill count of per-slot refill, and no free-slot seat."""
+    q = RequestQueue()
+    _submit(q, gens)
+    eng = _fleet(_cfg(), 1, slots=slots)[0]
+    PhaseStaggeredScheduler([eng], q, policy="none").run(max_ticks=500)
+    assert len(q.completed) == len(gens)
+    assert eng.assign_order == list(range(len(gens)))
+    assert eng.n_prefills == 1 and eng.n_refills == refills
+    assert _free_seats(eng) == 0
+
+
+# ---------------------------------------------------------------------------
 # scheduler phase staggering
 # ---------------------------------------------------------------------------
 
